@@ -41,7 +41,8 @@ def sweep(batch_sizes=(64, 256), rounds: int = 10, n_edges: int = 3000,
                 f_mem=f_mem, f_time=f_mem, f_emb=f_mem, m_r=10)
     cfg = pl.variant_config(variant, **dims)
     params = tgn.init_params(jax.random.key(0), cfg)
-    ef = jnp.asarray(g.edge_feats)
+    # the layout a serving session keeps resident (ops.row_table)
+    ef = kops.row_table(jnp.asarray(g.edge_feats))
 
     import numpy as np
 
@@ -61,18 +62,18 @@ def sweep(batch_sizes=(64, 256), rounds: int = 10, n_edges: int = 3000,
                 return _pipe.step(params, _aux, s, b, ef)
 
             # launches per compiled step (trace-time pallas-call counter)
+            state0 = pipe.resident(pipe.init_state())
             kops.reset_launch_count()
-            jax.jit(fn).lower(pipe.init_state(), batches[0])
+            jax.jit(fn).lower(state0, batches[0])
             launches = kops.launch_count()
 
             # materialized intermediate HBM bytes (kernels opaque)
             with kops.force_interpret(False):
-                traffic = hlo.step_traffic(fn, pipe.init_state(),
-                                           batches[0])
+                traffic = hlo.step_traffic(fn, state0, batches[0])
 
             # compile + warm into steady state (ring buffers filling)
             step = jax.jit(fn)
-            state = pipe.init_state()
+            state = state0
             for b in batches[:3]:
                 state = step(state, b).state
             jax.block_until_ready(state)

@@ -223,6 +223,17 @@ class TGNPipeline:
     def init_state(self) -> mailbox.VertexState:
         return tgn.init_state(self.cfg)
 
+    def resident(self, state: mailbox.VertexState) -> mailbox.VertexState:
+        """``state`` in the layout this tier keeps resident between steps:
+        the fused tier holds memory and mailbox in the kernel's row layout
+        (``stages.row_state``), every other tier the native one."""
+        return stages.row_state(state) if self.tier == "fused" else state
+
+    def native(self, state: mailbox.VertexState) -> mailbox.VertexState:
+        """Inverse of ``resident``: the native ``(V, f)`` tables."""
+        return (stages.native_state(state, self.cfg) if self.tier == "fused"
+                else state)
+
     # -- Algorithm 1 ---------------------------------------------------
     def step(self, params: dict, aux: dict, state: mailbox.VertexState,
              batch, edge_feats: jax.Array,
@@ -270,7 +281,7 @@ class TGNPipeline:
 
         # --- 4. cache new messages (Most-Recent aggregator == LWW commit) -
         mem_t = state.memory
-        fe = edge_feats[eid]
+        fe = stages.edge_rows(edge_feats, eid, self.cfg.f_edge)
         mail_src = memory.build_mail_raw(mem_t[src], mem_t[dst], fe)
         mail_dst = memory.build_mail_raw(mem_t[dst], mem_t[src], fe)
         new_mail = jnp.concatenate([mail_src, mail_dst], axis=0)
@@ -308,7 +319,7 @@ class TGNPipeline:
                          edge_feats, node_feats)
 
     def batched_step(self, aux: dict, *, donate_state: bool = False,
-                     in_shardings=None, out_shardings=None):
+                     in_shardings=None, out_shardings=None, tenant_map=None):
         """The cohort launch: ``jit(vmap(step))`` over a leading tenant axis.
 
         Signature of the returned callable:
@@ -323,6 +334,8 @@ class TGNPipeline:
         ``in_shardings``/``out_shardings`` pin the mesh placement of every
         operand (the sharded tenant fabric, serving/cluster.py); left
         ``None`` the launch follows its inputs (single-device serving).
+        ``tenant_map`` (``tgn_sharding.tenant_map``) wraps the vmapped
+        step to run per device on a mesh.
         """
         step = self.step
 
@@ -330,6 +343,8 @@ class TGNPipeline:
             return step(params, aux, state, batch, ef, nf)
 
         vstep = jax.vmap(one, in_axes=(None, 0, 0, None, None))
+        if tenant_map is not None:
+            vstep = tenant_map(vstep)
         kw = {}
         if in_shardings is not None:
             kw["in_shardings"] = in_shardings
@@ -413,7 +428,8 @@ class CoalescedRound:
     """
 
     def __init__(self, parts, *, donate_state: bool = False,
-                 in_shardings=None, out_shardings=None, obs=None):
+                 in_shardings=None, out_shardings=None, tenant_map=None,
+                 obs=None):
         """``parts``: sequence of ``(pipeline, aux, rows)`` — one entry per
         cohort, ``rows`` its stacked-table capacity. ``donate_state``
         donates the per-cohort state tuple (resident tables updated in
@@ -475,8 +491,10 @@ class CoalescedRound:
                 def one(pp, s, b, e, n, _step=step, _aux=aux):
                     return _step(pp, _aux, s, b, e, n)
 
-                outs.append(jax.vmap(one, in_axes=(None, 0, 0, None, None))(
-                    p, state, seg, ef, nf))
+                vstep = jax.vmap(one, in_axes=(None, 0, 0, None, None))
+                if tenant_map is not None:
+                    vstep = tenant_map(vstep)
+                outs.append(vstep(p, state, seg, ef, nf))
             return tuple(outs), jnp.sum(batch[4])
 
         kw = {}
@@ -499,6 +517,14 @@ class CoalescedRound:
             self._g_calls.set(self.calls)
         return self._fn(params, states, superbatch, edge_feats, node_feats,
                         tuple(int(w) for w in widths))
+
+    def lower(self, params: tuple, states: tuple, superbatch: tuple,
+              edge_feats, node_feats=None, *, widths: tuple):
+        """The round for these operands, lowered and not run (a
+        ``jax.stages.Lowered``: ``.compile().as_text()`` shows what the
+        chip executes)."""
+        return self._fn.lower(params, states, superbatch, edge_feats,
+                              node_feats, tuple(int(w) for w in widths))
 
 
 @functools.lru_cache(maxsize=64)

@@ -85,6 +85,31 @@ def resolved_tier(cfg, use_kernels) -> str:
     return tier
 
 
+def edge_rows(edge_feats: jax.Array, eids: jax.Array,
+              f_edge: int) -> jax.Array:
+    """Rows ``eids`` of the edge-feature table, native ``(..., f_edge)``:
+    the table is either native ``(E, f_edge)`` or in the kernels' row
+    layout (``kernels/ops.row_table``), which a serving session lays out
+    once at construction and every tier reads."""
+    x = edge_feats[eids]
+    return x if edge_feats.ndim == 2 else x[..., 0, :f_edge]
+
+
+def row_state(state):
+    """``state`` with memory and mailbox in the row layout the fused
+    kernel DMAs from (``kernels/ops.row_table``). The fused tier keeps its
+    resident state this way, so no step re-lays a table."""
+    from repro.kernels import ops as kops  # local: keep core importable
+    return state._replace(memory=kops.row_table(state.memory),
+                          mail=kops.row_table(state.mail))
+
+
+def native_state(state, cfg):
+    """Inverse of ``row_state``: the native ``(V, f)`` tables."""
+    return state._replace(memory=state.memory[..., 0, :cfg.f_mem],
+                          mail=state.mail[..., 0, :cfg.gru.f_mail_raw])
+
+
 class Neighborhood(NamedTuple):
     """What a sampler hands the aggregator.
 
@@ -331,7 +356,8 @@ def make_sampler(cfg):
                 state, vids)
             dt = jnp.maximum(t_query[:, None] - nbr_ts, 0.0) * valid
             s_nbr = state.memory[nbr_ids] * valid[..., None]
-            e_nbr = edge_feats[nbr_eid] * valid[..., None]
+            e_nbr = edge_rows(edge_feats, nbr_eid, cfg.f_edge) \
+                * valid[..., None]
             return Neighborhood(s_nbr=s_nbr, e_nbr=e_nbr, dt=dt, valid=valid,
                                 logits=None, full_logits=dt * 0.0,
                                 full_valid=valid, full_dt=dt)
@@ -345,7 +371,8 @@ def make_sampler(cfg):
     def sampler(params, aux, state, edge_feats, vids, t_query):
         sel = select(params, aux, state, vids, t_query)
         s_nbr = state.memory[sel.ids] * sel.valid[..., None]
-        e_nbr = edge_feats[sel.eids] * sel.valid[..., None]
+        e_nbr = edge_rows(edge_feats, sel.eids, cfg.f_edge) \
+            * sel.valid[..., None]
         return Neighborhood(s_nbr=s_nbr, e_nbr=e_nbr, dt=sel.dt,
                             valid=sel.valid, logits=sel.logits,
                             full_logits=sel.full_logits,
@@ -531,6 +558,11 @@ def make_fused_step(cfg):
     scratch instead of a scatter/gather HBM round-trip. The mail build and
     the state commits — genuine state writes the paper's design also pays —
     stay in XLA after the launch.
+
+    The kernel reads memory, mailbox and edge features in the row layout
+    (``row_state``, ``kernels/ops.row_table``), so the state and the edge
+    table must arrive in it — ``TGNPipeline.resident`` and a serving
+    session's edge table: laid out once, never per step.
     """
     from repro.kernels import ops as kops  # local: keep core importable
     from repro.core import tgn             # local: BatchOut (no cycle)
@@ -568,21 +600,26 @@ def make_fused_step(cfg):
               node_feats):
         src, dst, eid, ts, valid = batch
         B = src.shape[0]
+        if state.memory.ndim != 3 or edge_feats.ndim != 3:
+            raise ValueError("the fused tier steps row-layout tables: pass "
+                             "pipeline.resident(state) and "
+                             "kernels.ops.row_table(edge_feats)")
         winners = committer.winners(vids, vvalid, B)
         sel, h, s_upd, lu_upd = datapath(params, aux, state, edge_feats,
                                          vids, t_inst, winners)
-        state = committer.commit_memory(state, vids, winners, s_upd, lu_upd)
+        state = committer.commit_memory(state, vids, winners,
+                                        kops.row_table(s_upd), lu_upd)
         # mail build: committed memory of a VALID row r is exactly
         # s_upd[r] (duplicates of a vertex compute identical updates and
         # the LWW commit picks one), so the staged path's post-commit
         # memory gather is unnecessary; losers' mail is dropped by the
         # commit anyway.
-        fe = edge_feats[eid]
+        fe = edge_rows(edge_feats, eid, cfg.f_edge)
         mail_src = memory.build_mail_raw(s_upd[:B], s_upd[B:], fe)
         mail_dst = memory.build_mail_raw(s_upd[B:], s_upd[:B], fe)
         new_mail = jnp.concatenate([mail_src, mail_dst], axis=0)
-        state = committer.commit_mail(state, vids, winners, new_mail,
-                                      t_inst)
+        state = committer.commit_mail(state, vids, winners,
+                                      kops.row_table(new_mail), t_inst)
         state = mailbox.insert_neighbors(state, src, dst, eid, ts, valid)
         return tgn.BatchOut(state=state, emb_src=h[:B], emb_dst=h[B:],
                             attn_logits=sel.full_logits,

@@ -117,6 +117,24 @@ def out_specs(mesh: Mesh, state_like: mailbox.VertexState) -> tgn.BatchOut:
                         nbr_valid=t, nbr_dt=t)
 
 
+def tenant_map(mesh: Mesh):
+    """Wrapper for a vmapped cohort step ``f(params, state, batch,
+    edge_feats, node_feats) -> BatchOut``: run it under ``shard_map``,
+    each device stepping its own block of the tenant axis.
+
+    A Mosaic kernel cannot be partitioned by the compiler, so the step
+    must see per-device arrays. Every mesh axis other than ``tenant``
+    sees whole tables: a vertex-sharded state is gathered for the step
+    and re-sharded after it."""
+    t = P(_tenant_axis(mesh))
+
+    def wrap(f):
+        return jax.shard_map(f, mesh=mesh, in_specs=(P(), t, t, P(), P()),
+                             out_specs=t, check_vma=False)
+
+    return wrap
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     """The placement of cohort-shared operands (params, edge/node feature
     stores): one full copy per device."""

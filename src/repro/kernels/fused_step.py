@@ -15,9 +15,12 @@ This kernel is the whole post-prune datapath in ONE ``pallas_call``:
     computed from timestamps/ids only, upstream, preserving the
     prune-then-fetch contract of §III-B;
   * the vertex memory / mailbox / edge-feature tables stay in HBM
-    (``memory_space=ANY``); per batch tile the kernel DMAs exactly the k
-    winner rows (plus the tile's own mail/memory rows) into VMEM — the jax
-    analogue of the paper's prefetcher;
+    (``memory_space=ANY``) in the row layout of ``ops.row_table``
+    (``(rows, 1, W_p)``, W_p a LANE multiple); per batch tile the kernel
+    DMAs exactly the k winner rows (plus the tile's own mail/memory rows)
+    into ``(n, 1, W_p)`` VMEM row buffers — the jax analogue of the
+    paper's prefetcher. Both sides are row-addressable and lane-aligned,
+    which is what the TPU's DMA engine accepts for a one-row copy;
   * phase 0 (MUU): mail rows through the fused LUT+GRU -> updated memory
     rows, written both to the ``s_upd`` output and to a persistent VMEM
     scratch that spans the whole batch;
@@ -40,7 +43,9 @@ For paper dims (B=256 -> R=512, k=4, f_mem=100, f_edge=172, E=128) that is
 
 Per-row copies are issued through one DMA semaphore with an immediate
 wait; a production kernel would rotate a semaphore array to keep several
-row fetches in flight, which changes no numerics.
+row fetches in flight, which changes no numerics. The winner time deltas
+arrive flattened to ``(R*k, 1)`` by the wrapper: no reshape inside the
+kernel moves lanes into sublanes.
 """
 from __future__ import annotations
 
@@ -60,7 +65,7 @@ def _fused_kernel(  # scalar prefetch (SMEM)
                   # grid-blocked VMEM operands
                   dt_mail_ref, mail_ok_ref, sel_dt_ref, logits_ref,
                   valid_ref,
-                  # HBM-resident tables (manual DMA)
+                  # HBM-resident row tables (manual DMA)
                   mem_hbm, mail_hbm, ef_hbm,
                   # weights (VMEM, whole-array blocks)
                   w_i_ref, w_h_ref, b_i_ref, b_h_ref, gb_ref, gt_ref,
@@ -70,41 +75,37 @@ def _fused_kernel(  # scalar prefetch (SMEM)
                   h_ref, supd_ref,
                   # scratch
                   supd_all, mail_scr, self_scr, nbr_s, nbr_e, sem,
-                  *, k: int, f_mem: int, f_mail: int, f_edge: int,
-                  n_entries: int, block_b: int):
+                  *, k: int, f_edge: int, n_entries: int, block_b: int):
     """One grid step of the two-phase single-pass datapath (see module
     docstring for the shapes)."""
     ph = pl.program_id(0)
     t = pl.program_id(1)
     bb = block_b
-    m_p = supd_all.shape[1]
+    nk = bb * k
+    m_p = supd_all.shape[2]
+
+    def copy(src, dst):
+        cp = pltpu.make_async_copy(src, dst, sem)
+        cp.start()
+        cp.wait()
 
     @pl.when(ph == 0)
     def _muu():
         # --- prefetch: this tile's mail + pre-update memory rows ----------
-        mail_scr[...] = jnp.zeros_like(mail_scr)
-        self_scr[...] = jnp.zeros_like(self_scr)
-
         def fetch(i, _):
             v = vids_ref[t * bb + i]
-            cp = pltpu.make_async_copy(mail_hbm.at[v],
-                                       mail_scr.at[i, pl.ds(0, f_mail)], sem)
-            cp.start()
-            cp.wait()
-            cp = pltpu.make_async_copy(mem_hbm.at[v],
-                                       self_scr.at[i, pl.ds(0, f_mem)], sem)
-            cp.start()
-            cp.wait()
+            copy(mail_hbm.at[v], mail_scr.at[i])
+            copy(mem_hbm.at[v], self_scr.at[i])
             return 0
 
         jax.lax.fori_loop(0, bb, fetch, 0)
 
         # --- fused LUT + GRU (gate blocks at m_p strides) -----------------
-        gi = jnp.dot(mail_scr[...], w_i_ref[...],
-                     preferred_element_type=jnp.float32)
+        mail = mail_scr[...].reshape(bb, mail_scr.shape[2])
+        gi = jnp.dot(mail, w_i_ref[...], preferred_element_type=jnp.float32)
         gi = gi + b_i_ref[...]
         gi = gi + lut_rows(dt_mail_ref[...], gb_ref, gt_ref, n_entries)
-        s_prev = self_scr[...]
+        s_prev = self_scr[...].reshape(bb, m_p)
         gh = jnp.dot(s_prev, w_h_ref[...],
                      preferred_element_type=jnp.float32) + b_h_ref[...]
         r = jax.nn.sigmoid(gi[:, :m_p] + gh[:, :m_p])
@@ -114,7 +115,7 @@ def _fused_kernel(  # scalar prefetch (SMEM)
         s_upd = jnp.where(mail_ok_ref[...] > 0, s_new, s_prev)
 
         # persist for phase 1 (self rows AND same-batch neighbor overrides)
-        supd_all[pl.ds(t * bb, bb), :] = s_upd
+        supd_all[pl.ds(t * bb, bb)] = s_upd.reshape(bb, 1, m_p)
         supd_ref[...] = s_upd
         h_ref[...] = jnp.zeros_like(h_ref)
 
@@ -124,47 +125,32 @@ def _fused_kernel(  # scalar prefetch (SMEM)
         # Winners whose vertex was updated by THIS batch (hit >= 0) are
         # read back from the phase-0 scratch — the committed view — so the
         # kernel never needs the scatter/gather round-trip through HBM.
-        nbr_s[...] = jnp.zeros_like(nbr_s)
-        if f_edge:
-            nbr_e[...] = jnp.zeros_like(nbr_e)
-
         def fetch(j, _):
-            f = t * bb * k + j
+            f = t * nk + j
             hit = hit_ref[f]
 
             @pl.when(hit >= 0)
             def _():
-                cp = pltpu.make_async_copy(supd_all.at[hit], nbr_s.at[j],
-                                           sem)
-                cp.start()
-                cp.wait()
+                copy(supd_all.at[hit], nbr_s.at[j])
 
             @pl.when(hit < 0)
             def _():
-                cp = pltpu.make_async_copy(
-                    mem_hbm.at[sel_ids_ref[f]],
-                    nbr_s.at[j, pl.ds(0, f_mem)], sem)
-                cp.start()
-                cp.wait()
+                copy(mem_hbm.at[sel_ids_ref[f]], nbr_s.at[j])
 
             if f_edge:
-                cp = pltpu.make_async_copy(
-                    ef_hbm.at[sel_eid_ref[f]],
-                    nbr_e.at[j, pl.ds(0, f_edge)], sem)
-                cp.start()
-                cp.wait()
+                copy(ef_hbm.at[sel_eid_ref[f]], nbr_e.at[j])
             return 0
 
-        jax.lax.fori_loop(0, bb * k, fetch, 0)
+        jax.lax.fori_loop(0, nk, fetch, 0)
 
         # --- kv projection WITHOUT the concat: two split matmuls ----------
-        v = jnp.dot(nbr_s[...], wv_mem_ref[...],
+        v = jnp.dot(nbr_s[...].reshape(nk, m_p), wv_mem_ref[...],
                     preferred_element_type=jnp.float32)
         if f_edge:
-            v = v + jnp.dot(nbr_e[...], wv_edge_ref[...],
+            v = v + jnp.dot(nbr_e[...].reshape(nk, nbr_e.shape[2]),
+                            wv_edge_ref[...],
                             preferred_element_type=jnp.float32)
-        dt = sel_dt_ref[...].reshape(bb * k, 1)
-        v = v + lut_rows(dt, sb_ref, st_ref, n_entries)
+        v = v + lut_rows(sel_dt_ref[...], sb_ref, st_ref, n_entries)
         v = v + b_v_ref[...]
         d_p = v.shape[1]
         v = v.reshape(bb, k, d_p)
@@ -179,7 +165,7 @@ def _fused_kernel(  # scalar prefetch (SMEM)
 
         # --- FAM reduction + output transform (split, no concat) ---------
         agg = jnp.sum(attn[:, :, None] * v, axis=1)
-        fp = supd_all[pl.ds(t * bb, bb), :]
+        fp = supd_all[pl.ds(t * bb, bb)].reshape(bb, m_p)
         h = jnp.dot(fp, w_self_ref[...],
                     preferred_element_type=jnp.float32)
         h = h + jnp.dot(agg, w_agg_ref[...],
@@ -188,8 +174,7 @@ def _fused_kernel(  # scalar prefetch (SMEM)
         supd_ref[...] = fp
 
 
-@functools.partial(jax.jit, static_argnames=("k", "f_mem", "f_mail",
-                                             "f_edge", "block_b",
+@functools.partial(jax.jit, static_argnames=("k", "f_edge", "block_b",
                                              "interpret"))
 def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
                       sel_dt, sel_logits, sel_valid,
@@ -197,22 +182,25 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
                       w_i, w_h, b_i, b_h, g_bounds, g_table,
                       wv_mem, wv_edge, b_v, s_bounds, s_table,
                       w_self, w_agg, b_out,
-                      *, k: int, f_mem: int, f_mail: int, f_edge: int,
-                      block_b: int, interpret: bool = False):
+                      *, k: int, f_edge: int, block_b: int,
+                      interpret: bool = False):
     """One launch for the post-prune datapath of one batch.
 
     Scalar prefetch (int32): ``vids`` (R,), flat ``sel_ids``/``sel_eid``/
     ``hit`` (R*k,) — ``hit[f] >= 0`` redirects winner ``f`` to the phase-0
     updated row (its vertex was committed by this batch). Blocked operands:
-    ``dt_mail``/``mail_ok`` (R, 1), ``sel_dt``/``sel_logits``/``sel_valid``
-    (R, k). HBM tables: ``memory`` (V, f_mem), ``mail`` (V, f_mail),
-    ``edge_feats`` (E_rows, f_edge). Weights are kernel-layout (lane-padded
-    OUT dims, gate blocks at m_p strides; see ops.pad_fused_params).
+    ``dt_mail``/``mail_ok`` (R, 1), ``sel_dt`` (R*k, 1),
+    ``sel_logits``/``sel_valid`` (R, k). HBM row tables (``ops.row_table``
+    layout, zero lane padding): ``memory`` (V, 1, m_p), ``mail``
+    (V, 1, f_p), ``edge_feats`` (E_rows, 1, e_p). Weights are
+    kernel-layout (lane-padded OUT dims, gate blocks at m_p strides; see
+    ops.pad_fused_params).
     R must be a multiple of ``block_b``. Returns ``(h, s_upd)`` —
     (R, emb_p) embeddings and (R, m_p) updated memory rows.
     """
     R = vids.shape[0]
     assert R % block_b == 0, (R, block_b)
+    assert sel_dt.shape == (R * k, 1), (sel_dt.shape, R, k)
     m_p = w_h.shape[0]
     d_p = wv_mem.shape[1]
     e_p = wv_edge.shape[0]
@@ -221,6 +209,9 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
     f_p = w_i.shape[0]
     T = R // block_b
     nk = block_b * k
+    assert memory.shape[1:] == (1, m_p), (memory.shape, m_p)
+    assert mail.shape[1:] == (1, f_p), (mail.shape, f_p)
+    assert edge_feats.shape[1:] == (1, e_p), (edge_feats.shape, e_p)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -228,12 +219,12 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
         in_specs=[
             pl.BlockSpec((block_b, 1), lambda ph, t, *_: (t, 0)),
             pl.BlockSpec((block_b, 1), lambda ph, t, *_: (t, 0)),
+            pl.BlockSpec((nk, 1), lambda ph, t, *_: (t, 0)),
             pl.BlockSpec((block_b, k), lambda ph, t, *_: (t, 0)),
             pl.BlockSpec((block_b, k), lambda ph, t, *_: (t, 0)),
-            pl.BlockSpec((block_b, k), lambda ph, t, *_: (t, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),       # memory table
-            pl.BlockSpec(memory_space=pltpu.ANY),       # mailbox table
-            pl.BlockSpec(memory_space=pltpu.ANY),       # edge features
+            pl.BlockSpec(memory_space=pl.ANY),          # memory table
+            pl.BlockSpec(memory_space=pl.ANY),          # mailbox table
+            pl.BlockSpec(memory_space=pl.ANY),          # edge features
             pl.BlockSpec((f_p, 3 * m_p), lambda ph, t, *_: (0, 0)),
             pl.BlockSpec((m_p, 3 * m_p), lambda ph, t, *_: (0, 0)),
             pl.BlockSpec((1, 3 * m_p), lambda ph, t, *_: (0, 0)),
@@ -254,17 +245,17 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
             pl.BlockSpec((block_b, m_p), lambda ph, t, *_: (t, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((R, m_p), jnp.float32),          # updated rows
-            pltpu.VMEM((block_b, f_p), jnp.float32),    # mail tile
-            pltpu.VMEM((block_b, m_p), jnp.float32),    # pre-update memory
-            pltpu.VMEM((nk, m_p), jnp.float32),         # winner memory rows
-            pltpu.VMEM((nk, e_p), jnp.float32),         # winner edge rows
+            pltpu.VMEM((R, 1, m_p), jnp.float32),       # updated rows
+            pltpu.VMEM((block_b, 1, f_p), jnp.float32),  # mail tile
+            pltpu.VMEM((block_b, 1, m_p), jnp.float32),  # pre-update memory
+            pltpu.VMEM((nk, 1, m_p), jnp.float32),      # winner memory rows
+            pltpu.VMEM((nk, 1, e_p), jnp.float32),      # winner edge rows
             pltpu.SemaphoreType.DMA,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_fused_kernel, k=k, f_mem=f_mem, f_mail=f_mail,
-                          f_edge=f_edge, n_entries=E, block_b=block_b),
+        functools.partial(_fused_kernel, k=k, f_edge=f_edge, n_entries=E,
+                          block_b=block_b),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((R, emb_p), jnp.float32),
                    jax.ShapeDtypeStruct((R, m_p), jnp.float32)],

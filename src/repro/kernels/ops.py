@@ -2,9 +2,16 @@
 
 These wrappers own all the padding/unpadding between the paper's native dims
 (f_mem=100, f_edge=172, ...) and the LANE(128)-aligned shapes the kernels
-require, pick interpret mode automatically off-TPU, and repack the core/
-parameter layout (gate blocks at f_mem strides) into the lane-aligned kernel
-layout (gate blocks at m_p strides).
+require, pick interpret mode on the CPU backend (compiled Mosaic on a TPU,
+an error anywhere else), and repack the core/ parameter layout (gate blocks
+at f_mem strides) into the lane-aligned kernel layout (gate blocks at m_p
+strides).
+
+Tables the fused kernel reads row by row (vertex memory, mailbox, edge
+features) use the **row layout** of ``row_table``: ``(rows, 1, W_p)`` with
+``W_p`` a LANE multiple. The TPU lays such an array out with (1, 128) tiles,
+so one row is one aligned DMA; a 2-D ``(rows, W)`` table is tiled (8, 128)
+and the chip's compiler refuses a one-row slice of it.
 """
 from __future__ import annotations
 
@@ -40,9 +47,19 @@ def force_interpret(mode: bool | None):
 
 
 def _use_interpret() -> bool:
+    """Interpret on the CPU backend, compile for a TPU, refuse anything
+    else: a kernel that silently interprets on an accelerator would hide
+    the device it was meant to run on."""
     if _INTERPRET["override"] is not None:
         return bool(_INTERPRET["override"])
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run compiled on a TPU or "
+                       f"interpreted on the CPU; backend {backend!r} is "
+                       f"neither")
 
 
 #: Trace-time kernel-launch counter: every public entry point below bumps
@@ -67,6 +84,15 @@ def _count_launch() -> None:
 
 def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
     return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+
+
+def row_table(x: jax.Array) -> jax.Array:
+    """(..., rows, W) -> (..., rows, 1, W_p) float32, zero-padded to a
+    LANE multiple: the row layout the fused kernel DMAs single rows from
+    (see the module docstring). Lay a table out once and keep it."""
+    w = x.shape[-1]
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, round_up(max(w, 1)) - w)]
+    return jnp.pad(x.astype(jnp.float32), pad)[..., None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -208,9 +234,9 @@ def sat_aggregate(kv: jax.Array, dt: jax.Array, logits: jax.Array,
     kv_p = jnp.pad(kv.astype(jnp.float32),
                    ((0, B_p - B), (0, 0), (0, dkv_p - dkv)))
     pad_rows = ((0, B_p - B), (0, 0))
+    dt_flat = jnp.pad(dt.astype(jnp.float32), pad_rows).reshape(B_p * k, 1)
     out = sat_aggregate_pallas(
-        kv_p, jnp.pad(dt.astype(jnp.float32), pad_rows),
-        jnp.pad(logits.astype(jnp.float32), pad_rows),
+        kv_p, dt_flat, jnp.pad(logits.astype(jnp.float32), pad_rows),
         jnp.pad(valid.astype(jnp.float32), pad_rows),
         packed["w_v"], packed["b_v"], packed["bounds"], packed["table"],
         block_b=bb, interpret=_use_interpret())
@@ -227,9 +253,9 @@ def pad_fused_params(gru_params: dict, attn_params: dict, folded_gru: dict,
                      f_edge: int) -> dict:
     """Kernel-layout parameter pack for the fused single-pass step.
 
-    Everything the one-launch datapath consumes, padded on OUT dims only
-    (IN rows are DMA'd at native table widths into zero-padded VMEM
-    scratch, so zero-padding weight ROWS keeps the math exact):
+    Everything the one-launch datapath consumes, lane-padded (the row
+    tables the kernel DMAs from carry zero lane padding, so zero-padding
+    weight ROWS keeps the math exact):
 
       * the raw-mail GRU weights at m_p gate strides (pad_gru_params) plus
         the GRU-folded LUT table gate-repacked to (E, 3*m_p);
@@ -280,7 +306,7 @@ def fused_step(vids: jax.Array, sel_ids: jax.Array, sel_eid: jax.Array,
                hit: jax.Array, dt_mail: jax.Array, mail_ok: jax.Array,
                sel_dt: jax.Array, sel_logits: jax.Array,
                sel_valid: jax.Array, memory: jax.Array, mail: jax.Array,
-               edge_feats: jax.Array | None, packed: dict,
+               edge_feats: jax.Array, packed: dict,
                *, block_b: int = 128):
     """ONE launch for the post-prune datapath: winner-row gather + kv
     projection + folded-LUT rows + masked softmax + FAM + output transform
@@ -291,8 +317,9 @@ def fused_step(vids: jax.Array, sel_ids: jax.Array, sel_eid: jax.Array,
     batch and names the batch row holding its updated memory (the
     committed view); ``dt_mail``/``mail_ok`` (R,); ``sel_dt``/
     ``sel_logits``/``sel_valid`` (R, k). ``memory``/``mail``/
-    ``edge_feats`` are the HBM-resident tables — the kernel fetches only
-    the addressed rows. Returns ``(h (R, f_emb), s_upd (R, f_mem))``.
+    ``edge_feats`` are the HBM-resident tables in the ``row_table``
+    layout — the kernel fetches only the addressed rows, and nothing here
+    copies a table. Returns ``(h (R, f_emb), s_upd (R, f_mem))``.
     """
     _count_launch()
     R, k = sel_ids.shape
@@ -307,20 +334,18 @@ def fused_step(vids: jax.Array, sel_ids: jax.Array, sel_eid: jax.Array,
     def f32(x, padder=p1):
         return jnp.pad(x.astype(jnp.float32), padder)
 
-    ef = (edge_feats.astype(jnp.float32) if packed["f_edge"]
-          else jnp.zeros((1, 1), jnp.float32))
     h, s_upd = fused_step_pallas(
         i32(vids), i32(sel_ids, p2).reshape(-1),
         i32(sel_eid, p2).reshape(-1),
         i32(hit, p2, fill=-1).reshape(-1),
         f32(dt_mail)[:, None], f32(mail_ok)[:, None],
-        f32(sel_dt, p2), f32(sel_logits, p2), f32(sel_valid, p2),
-        memory.astype(jnp.float32), mail.astype(jnp.float32), ef,
+        f32(sel_dt, p2).reshape(R_p * k, 1), f32(sel_logits, p2),
+        f32(sel_valid, p2), memory, mail, edge_feats,
         packed["w_i"], packed["w_h"], packed["b_i"], packed["b_h"],
         packed["g_bounds"], packed["g_table"], packed["wv_mem"],
         packed["wv_edge"], packed["b_v"], packed["s_bounds"],
         packed["s_table"], packed["w_self"], packed["w_agg"],
         packed["b_out"],
-        k=k, f_mem=packed["f_mem"], f_mail=packed["f_mail"],
-        f_edge=packed["f_edge"], block_b=bb, interpret=_use_interpret())
+        k=k, f_edge=packed["f_edge"], block_b=bb,
+        interpret=_use_interpret())
     return h[:R, :packed["f_emb"]], s_upd[:R, :packed["f_mem"]]

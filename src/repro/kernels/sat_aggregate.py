@@ -34,7 +34,8 @@ def _sat_kernel(kv_ref, dt_ref, logits_ref, valid_ref, w_v_ref, b_v_ref,
                 bounds_ref, table_ref, out_ref, *, k: int, n_entries: int):
     """One batch tile.  Shapes (VMEM):
     kv (Bb, k*Dkv) — k pre-gathered neighbor rows, flattened;
-    dt (Bb, k), logits (Bb, k), valid (Bb, k) float {0,1};
+    dt (Bb*k, 1) — flattened by the caller, so no lane->sublane reshape
+    happens in the kernel; logits (Bb, k), valid (Bb, k) float {0,1};
     w_v (Dkv, D), b_v (1, D), bounds (1, n_entries), table (n_entries, D);
     out (Bb, D).
     """
@@ -47,8 +48,7 @@ def _sat_kernel(kv_ref, dt_ref, logits_ref, valid_ref, w_v_ref, b_v_ref,
 
     # LUT time rows (lut_time_encode.lut_rows: the one shared bucketing
     # definition across every kernel tier)
-    dt = dt_ref[...].reshape(bb * k, 1)
-    v = v + lut_rows(dt, bounds_ref, table_ref, n_entries)
+    v = v + lut_rows(dt_ref[...], bounds_ref, table_ref, n_entries)
     v = v + b_v_ref[...]
     v = v.reshape(bb, k, d)
 
@@ -72,8 +72,9 @@ def sat_aggregate_pallas(kv: jax.Array, dt: jax.Array, logits: jax.Array,
     """Fused V-projection + LUT + masked-softmax aggregation.
 
     kv (B, k, Dkv) float32 — pruned, pre-gathered neighbor features (memory
-    || edge feature), zero where invalid; dt/logits (B, k); valid (B, k)
-    float {0,1}; w_v (Dkv, D); b_v (1, D); bounds (1, E); table (E, D).
+    || edge feature), zero where invalid; dt (B*k, 1), row-major over
+    (B, k); logits (B, k); valid (B, k) float {0,1}; w_v (Dkv, D);
+    b_v (1, D); bounds (1, E); table (E, D).
     B multiple of block_b; Dkv and D LANE-aligned. Returns (B, D).
     """
     B, k, dkv = kv.shape
@@ -81,13 +82,14 @@ def sat_aggregate_pallas(kv: jax.Array, dt: jax.Array, logits: jax.Array,
     E = table.shape[0]
     assert B % block_b == 0, (B, block_b)
     assert bounds.shape == (1, E)
+    assert dt.shape == (B * k, 1), (dt.shape, B, k)
     grid = (B // block_b,)
     return pl.pallas_call(
         functools.partial(_sat_kernel, k=k, n_entries=E),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, k * dkv), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, k), lambda i: (i, 0)),
+            pl.BlockSpec((block_b * k, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_b, k), lambda i: (i, 0)),
             pl.BlockSpec((block_b, k), lambda i: (i, 0)),
             pl.BlockSpec((dkv, d), lambda i: (0, 0)),

@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import use_compile_cache
+
 
 class _SnapshotHooks:
     """--snapshot-dir plumbing: periodic fleet snapshots + --restore.
@@ -614,6 +616,7 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
     if args.restore and not args.snapshot_dir:
         ap.error("--restore needs --snapshot-dir")
     if args.snapshot_every and not args.snapshot_dir:

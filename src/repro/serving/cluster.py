@@ -65,7 +65,7 @@ class _ShardedCohort(_Cohort):
 
     def _build_launches(self) -> None:
         super()._build_launches()        # keeps the unsharded _vstep1 peek
-        like = jax.eval_shape(self.pipeline.init_state)
+        like = jax.eval_shape(self._init_row)
         self.state_shardings = tsh.make_shardings(
             self.mesh, tsh.state_specs(self.mesh, like))
         rep = tsh.replicated(self.mesh)
@@ -76,11 +76,13 @@ class _ShardedCohort(_Cohort):
             self.mesh, tsh.out_specs(self.mesh, like))
         # node_feats may be None: leave its placement unspecified
         in_sh = (rep, self.state_shardings, batch_sh, rep, None)
+        tmap = tsh.tenant_map(self.mesh)
         self._vstep = self.pipeline.batched_step(
-            self.aux, in_shardings=in_sh, out_shardings=self.out_shardings)
+            self.aux, in_shardings=in_sh, out_shardings=self.out_shardings,
+            tenant_map=tmap)
         self._vstep_commit = self.pipeline.batched_step(
             self.aux, donate_state=True, in_shardings=in_sh,
-            out_shardings=self.out_shardings)
+            out_shardings=self.out_shardings, tenant_map=tmap)
 
     def _target_capacity(self, n: int) -> int:
         """Mesh-aligned capacity: the reserve ladder (when enabled) picks
@@ -154,7 +156,7 @@ class ShardedSessionManager(SessionManager):
         return pl.CoalescedRound(
             [(c.pipeline, c.aux, c.capacity) for c in cohorts],
             donate_state=True, in_shardings=in_sh, out_shardings=out_sh,
-            obs=self.obs)
+            tenant_map=tsh.tenant_map(self.mesh), obs=self.obs)
 
     def _make_stager(self, rows: int, width: int):
         from repro.serving.session import _HostStager

@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core import mailbox, pipeline as pl, tgn
 from repro.data.stream import EdgeBatch
+from repro.kernels import ops as kops
 from repro.obs import Histogram, MetricsRegistry
 
 
@@ -362,6 +363,10 @@ class _Cohort:
 
         self._vstep1 = jax.jit(one_t)
 
+    def _init_row(self):
+        """One idle tenant's state in the tier's resident layout."""
+        return self.pipeline.resident(self.pipeline.init_state())
+
     @property
     def size(self) -> int:
         return len(self.tids)
@@ -392,7 +397,7 @@ class _Cohort:
         n = int(state.memory.shape[0])
         cap = self._target_capacity(len(self.tids))
         if cap > n:
-            row = self.pipeline.init_state()
+            row = self._init_row()
             pads = jax.tree.map(lambda x: jnp.repeat(x[None], cap - n,
                                                      axis=0), row)
             state = jax.tree.map(lambda t, p: jnp.concatenate([t, p],
@@ -411,7 +416,7 @@ class _Cohort:
         first tenant arrives, so that first attach is a fast path)."""
         if self.state is None:
             empty = jax.tree.map(lambda x: x[None][:0],
-                                 self.pipeline.init_state())
+                                 self._init_row())
             self.state = self._fit(empty)
 
     def add(self, tid: str) -> bool:
@@ -425,12 +430,12 @@ class _Cohort:
             # fast path: the new tenant's init-state row overwrites an
             # idle spare slot (spares already hold init rows, but a slot
             # freed by a detach holds the departed tenant's stale rows)
-            row = self.pipeline.init_state()
+            row = self._init_row()
             self.state = self._place(jax.tree.map(
                 lambda t, r: t.at[n].set(r), self.state, row))
             self.tids.append(tid)
             return False
-        row = jax.tree.map(lambda x: x[None], self.pipeline.init_state())
+        row = jax.tree.map(lambda x: x[None], self._init_row())
         if self.state is None:
             st = row
         else:
@@ -529,7 +534,10 @@ class SessionManager:
         #: DEFAULT_PARAMS entry, more arrive via ``register_params``
         self.param_store = ParamStore(params, place=self._place_params)
         self.params = self.param_store.get(DEFAULT_PARAMS)
-        self.edge_feats = jnp.asarray(edge_feats)
+        #: the shared edge-feature table, laid out ONCE in the kernels' row
+        #: layout (``kernels/ops.row_table``): every tier reads it, and the
+        #: fused kernel DMAs single rows from it without a per-step copy
+        self.edge_feats = kops.row_table(jnp.asarray(edge_feats))
         self.node_feats = (jnp.asarray(node_feats)
                            if node_feats is not None else None)
         # keyed by (cfg, resolved kernel tier, param-set name): tenants
@@ -823,16 +831,18 @@ class SessionManager:
         return self._tenant_cohort[tid]
 
     def state_of(self, tid: str) -> mailbox.VertexState:
-        """The tenant's (unbatched) VertexState view."""
+        """The tenant's (unbatched) VertexState view, native tables."""
         cohort = self._tenant_cohort[tid]
         i = cohort.tids.index(tid)
-        return jax.tree.map(lambda x: x[i], cohort.state)
+        return cohort.pipeline.native(
+            jax.tree.map(lambda x: x[i], cohort.state))
 
     def set_state(self, tid: str, st: mailbox.VertexState) -> None:
         cohort = self._tenant_cohort[tid]
         i = cohort.tids.index(tid)
         cohort.state = jax.tree.map(lambda t, r: t.at[i].set(r),
-                                    cohort.state, st)
+                                    cohort.state,
+                                    cohort.pipeline.resident(st))
 
     def _cohort_info(self, c: _Cohort) -> dict:
         return {"tenants": tuple(c.tids), "capacity": c.capacity,
@@ -990,6 +1000,17 @@ class SessionManager:
                     outs[tid] = self._slice_out(out, i, host[tid][0].shape[0])
         return outs, edges
 
+    def lower_round(self, width: int):
+        """The fleet's coalesced round lowered, not run, with every lane
+        at batch ``width`` — what one such round compiles to."""
+        launch = self._ensure_layout(width)
+        cohorts = list(self._cohorts.values())
+        return launch.lower(tuple(c.params for c in cohorts),
+                            tuple(c.state for c in cohorts),
+                            self._stager.stage({}), self.edge_feats,
+                            self.node_feats,
+                            widths=(width,) * len(cohorts))
+
     def _device_staged(self, batches: Mapping) -> bool:
         """True when the fleet is a single-tenant view being fed an
         already-on-device batch tuple (StreamingEngine's prefetched
@@ -1110,11 +1131,13 @@ class SessionManager:
         cohort = self._tenant_cohort[tid]
         dev = _as_device_tuple(batch)
         if cohort.size == 1 and cohort.capacity == 1:
-            return cohort._vstep1(cohort.params, cohort.state, dev,
-                                  self.edge_feats, self.node_feats)
-        out = self._cohort_round(cohort, {tid: dev})
-        return self._slice_out(out, cohort.tids.index(tid),
-                               dev[0].shape[0], with_state=True)
+            out = cohort._vstep1(cohort.params, cohort.state, dev,
+                                 self.edge_feats, self.node_feats)
+        else:
+            out = self._slice_out(self._cohort_round(cohort, {tid: dev}),
+                                  cohort.tids.index(tid), dev[0].shape[0],
+                                  with_state=True)
+        return out._replace(state=cohort.pipeline.native(out.state))
 
     # -- stream driving ------------------------------------------------
     def run(self, streams: Mapping[str, Iterable]):

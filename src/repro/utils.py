@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 import zlib
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
@@ -17,6 +18,27 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+#: the checkout this package was loaded from (``<checkout>/src/repro``)
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as JAX reads it:
+    nothing here names another directory. Otherwise the cache lives at
+    ``<checkout>/.jax_cache``, a fixed path (the path is part of the cache
+    key, so a directory that moves never hits). Call it from a program's
+    entry point, never at import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 # ---------------------------------------------------------------------------
 # RNG helpers

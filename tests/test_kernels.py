@@ -169,12 +169,14 @@ def test_fused_step_matches_staged_trajectory(variant):
     a ragged round whose padding rows must commit nothing."""
     g, staged, fused, params = _fused_setup(variant)
     ef = jnp.asarray(g.edge_feats)
-    ss, sf = staged.init_state(), fused.init_state()
+    ef_rows = ops.row_table(ef)
+    ss, sf = staged.init_state(), fused.resident(fused.init_state())
     assert fused.tier == "fused" and fused.stages.fused is not None
     for b in _batches(g, 4, 30, ragged=True):
         os_ = staged.step_fn(params, ss, b, ef)
-        of_ = fused.step_fn(params, sf, b, ef)
+        of_ = fused.step_fn(params, sf, b, ef_rows)
         ss, sf = os_.state, of_.state
+        sf_native = fused.native(sf)
         m = np.asarray(b[4])[:, None]
         np.testing.assert_allclose(
             np.asarray((os_.emb_src - of_.emb_src)) * m, 0.0, atol=2e-5)
@@ -189,7 +191,7 @@ def test_fused_step_matches_staged_trajectory(variant):
                       "nbr_cursor"):
             np.testing.assert_allclose(
                 np.asarray(getattr(ss, field)),
-                np.asarray(getattr(sf, field)), atol=2e-5,
+                np.asarray(getattr(sf_native, field)), atol=2e-5,
                 err_msg=f"{variant}:{field}")
 
 
@@ -198,8 +200,8 @@ def test_fused_step_all_invalid_batch_is_bitwise_noop():
     the vertex state bitwise untouched — the idle-masking contract every
     serving layer relies on."""
     g, staged, fused, params = _fused_setup("sat+lut+np4", key=3)
-    ef = jnp.asarray(g.edge_feats)
-    state = fused.init_state()
+    ef = ops.row_table(jnp.asarray(g.edge_feats))
+    state = fused.resident(fused.init_state())
     for b in _batches(g, 2, 25):
         state = fused.step_fn(params, state, b, ef).state
     B = 13
@@ -225,6 +227,7 @@ def test_fused_step_is_one_kernel_launch():
         staged.init_state())
     assert ops.launch_count() == 3
     ops.reset_launch_count()
-    jax.jit(lambda s: fused.step(params, aux_f, s, b, ef)).lower(
-        fused.init_state())
+    ef_rows = ops.row_table(ef)
+    jax.jit(lambda s: fused.step(params, aux_f, s, b, ef_rows)).lower(
+        fused.resident(fused.init_state()))
     assert ops.launch_count() == 1
