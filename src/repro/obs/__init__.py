@@ -10,10 +10,12 @@ The measurement plane of the serving stack (ROADMAP item 3's substrate):
     ``serving/engine.py`` and ``serving/session.py``.
 
 ``trace``
-    span-based round tracing with explicit clock injection (the
-    frontend's fake-clock discipline) and Chrome/Perfetto
-    ``trace_event`` + JSON-lines export. Sampled: fencing the async
-    round pipeline happens at trace-sample rounds ONLY.
+    ``span``: the round's host phases as ``jax.profiler`` annotations,
+    on the device's clock in any profiler trace; and span-based round
+    tracing with explicit clock injection (the frontend's fake-clock
+    discipline) and Chrome/Perfetto ``trace_event`` + JSON-lines export.
+    Sampled: fencing the async round pipeline happens at trace-sample
+    rounds ONLY.
 
 ``slo``
     per-tenant latency-objective tracking — target vs observed p99 and
@@ -25,7 +27,7 @@ SLO semantics.
 """
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.slo import SLOTracker
-from repro.obs.trace import RoundTracer, Span
+from repro.obs.trace import RoundTracer, Span, span
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "RoundTracer", "SLOTracker", "Span"]
+           "RoundTracer", "SLOTracker", "Span", "span"]

@@ -1,4 +1,12 @@
-"""Span-based round tracing with sampled device fencing.
+"""Span-based round tracing: profiler spans, and sampled device fencing.
+
+``span(name, trace)`` opens a ``jax.profiler.TraceAnnotation`` around a
+phase of the round's host work (``session.step``, ``session.stage``,
+``session.dispatch``, ...). Those spans land in any profiler trace on the
+same clock as the device's operations, so an idle gap on the device can be
+put down to the host phase that was open at the time. With no profiler
+running a span costs its annotation's construction and nothing else: it
+never fences and never reads a device value.
 
 ``RoundTracer`` records named spans — ``ingest``/``flush`` (frontend),
 ``stage``/``launch`` (host side of the coalesced round), ``h2d``/``drain``
@@ -30,8 +38,9 @@ from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass(frozen=True)
@@ -96,12 +105,6 @@ class RoundTracer:
             return
         self.spans.append(Span(name, cat, float(t0), float(t1), args))
 
-    @contextmanager
-    def span(self, name: str, cat: str = "round", **args):
-        t0 = self.clock()
-        yield
-        self.add(name, t0, self.clock(), cat=cat, **args)
-
     # -------------------------------------------------------- reading
     def summary(self) -> dict:
         """``{span name: {count, total_s}}`` plus the sampling tallies."""
@@ -144,3 +147,47 @@ class RoundTracer:
         with open(path, "w") as f:
             for s in self.spans:
                 f.write(json.dumps(s.as_dict()) + "\n")
+
+
+#: profiler span -> the ``RoundTracer`` span (name, category) it is also
+#: recorded as on sampled rounds
+RECORDED_AS = {"session.stage": ("stage", "host"),
+               "session.dispatch": ("launch", "host"),
+               "frontend.flush": ("flush", "frontend")}
+
+
+def span(name: str, trace: RoundTracer | None = None, **args):
+    """A context manager around one phase of the round's host work.
+
+    Always a ``jax.profiler.TraceAnnotation(name, **args)``; the profiler
+    encodes ``args`` only while it runs. ``trace`` is the caller's
+    sampled-round tracer handle (``None`` on unsampled rounds): when it is
+    given and ``name`` is in ``RECORDED_AS``, the span is also recorded
+    into the tracer on its injected clock, with ``args``. The recorded
+    span exposes ``t0`` (tracer clock) and ``args`` (more may be added
+    inside the block). ``args`` are a few host numbers, never one per
+    tenant."""
+    ann = TraceAnnotation(name, **args)
+    if trace is None or name not in RECORDED_AS:
+        return ann
+    return _Recorded(ann, trace, *RECORDED_AS[name], args)
+
+
+class _Recorded:
+    """``span`` on a sampled round: the annotation, and its tracer copy."""
+
+    def __init__(self, ann, trace: RoundTracer, name: str, cat: str,
+                 args: dict):
+        self.ann, self.trace = ann, trace
+        self.name, self.cat, self.args = name, cat, args
+        self.t0 = None
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = self.trace.clock()
+        return self
+
+    def __exit__(self, *exc):
+        self.trace.add(self.name, self.t0, self.trace.clock(), cat=self.cat,
+                       **self.args)
+        return self.ann.__exit__(*exc)

@@ -47,6 +47,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro.data.stream import EdgeBatch
+from repro.obs import span
 
 
 class RetryAfter(Exception):
@@ -375,29 +376,28 @@ class ServingFrontend:
         # time flush/ingest only when this round will carry spans
         trace = (tracer if tracer is not None and tracer.would_sample()
                  else None)
-        if trace is not None:
-            t_flush = trace.clock()
-        batches, arrivals = self.batcher.take()
+        with span("frontend.flush", trace) as flush:
+            batches, arrivals = self.batcher.take()
+            if self.round_log is not None and batches:
+                self.round_log.append(batches)
+            if self.journal is not None:
+                # WAL flush markers (gated: session_lint rule 5), written
+                # BEFORE the state transition so replay can rebuild this
+                # exact batch boundary. A quarantined tenant's batch is
+                # DROPPED by step() — no marker, so its journaled events
+                # stay pending and a post-restore replay re-applies them.
+                qset = getattr(self.mgr, "quarantined", frozenset())
+                for jtid, arr in arrivals.items():
+                    if jtid in qset:
+                        continue
+                    self.journal.note_flush(jtid, len(arr),
+                                            batches[jtid].src.shape[0])
+            if trace is not None:
+                flush.args["tenants"] = len(batches)
         if not batches:
             return {}
-        if self.round_log is not None:
-            self.round_log.append(batches)
-        if self.journal is not None:
-            # WAL flush markers (gated: session_lint rule 5), written
-            # BEFORE the state transition so replay can rebuild this
-            # exact batch boundary. A quarantined tenant's batch is
-            # DROPPED by step() — no marker, so its journaled events
-            # stay pending and a post-restore replay re-applies them.
-            qset = getattr(self.mgr, "quarantined", frozenset())
-            for jtid, arr in arrivals.items():
-                if jtid in qset:
-                    continue
-                self.journal.note_flush(jtid, len(arr),
-                                        batches[jtid].src.shape[0])
         if trace is not None:
             t_step = trace.clock()
-            trace.add("flush", t_flush, t_step, cat="frontend",
-                      tenants=len(batches))
             oldest = min(a for arr in arrivals.values() for a in arr)
             # queueing span of the round's oldest event: its arrival on
             # the shared clock -> the moment the round enters the session

@@ -61,7 +61,7 @@ import numpy as np
 from repro.core import mailbox, pipeline as pl, tgn
 from repro.data.stream import EdgeBatch
 from repro.kernels import ops as kops
-from repro.obs import Histogram, MetricsRegistry
+from repro.obs import Histogram, MetricsRegistry, span
 
 
 def _as_device_tuple(batch) -> tuple:
@@ -151,7 +151,8 @@ class _HostStager:
         self._turn = 1 - turn
         prev = self._inflight[turn]
         if prev is not None:             # reuse gate: transfer + consumer
-            jax.block_until_ready(prev)
+            with span("session.stage_wait"):
+                jax.block_until_ready(prev)
         buf = self._bufs[turn]
         for field in buf:
             field.fill(0)                # deterministic padding rows
@@ -959,45 +960,43 @@ class SessionManager:
             c = self._tenant_cohort[tid]
             rows[offsets[id(c)] + c.tids.index(tid)] = h
             widths[id(c)] = max(widths.get(id(c), 1), h[0].shape[0])
+        with span("session.stage", trace, rows=len(rows),
+                  width=width) as staged:
+            superbatch = self._stager.stage(rows)
+        with span("session.dispatch", trace, lanes=len(cohorts)):
+            states = tuple(c.state for c in cohorts)
+            # per-segment padded widths (static): each cohort steps at ITS
+            # round-max batch size — the exact B the per-cohort launch
+            # would use, which the bitwise contract requires (idle cohorts
+            # run a width-1 masked no-op lane). Params are per-lane too:
+            # each segment consumes its cohort's resident set
+            # (teacher/student A/B lanes in the same launch).
+            outs_t, edges = launch(tuple(c.params for c in cohorts), states,
+                                   superbatch, self.edge_feats,
+                                   self.node_feats,
+                                   widths=tuple(widths.get(id(c), 1)
+                                                for c in cohorts))
+            # the staged set may zero-copy alias host memory: its reuse
+            # must also wait for this launch, not just the transfer. Gate
+            # on the edge-count output — the state outputs become DONATED
+            # inputs of the next round (sharded cohorts), which
+            # block_until_ready rejects
+            self._stager.note_consumer(edges)
         if trace is not None:
-            t_stage = trace.clock()
-        superbatch = self._stager.stage(rows)
-        if trace is not None:
-            t_launch = trace.clock()
-            trace.add("stage", t_stage, t_launch, cat="host",
-                      rows=len(rows), width=width)
-        states = tuple(c.state for c in cohorts)
-        # per-segment padded widths (static): each cohort steps at ITS
-        # round-max batch size — the exact B the per-cohort launch would
-        # use, which the bitwise contract requires (idle cohorts run a
-        # width-1 masked no-op lane). Params are per-lane too: each
-        # segment consumes its cohort's resident set (teacher/student
-        # A/B lanes in the same launch).
-        outs_t, edges = launch(tuple(c.params for c in cohorts), states,
-                               superbatch, self.edge_feats, self.node_feats,
-                               widths=tuple(widths.get(id(c), 1)
-                                            for c in cohorts))
-        # the staged set may zero-copy alias host memory: its reuse must
-        # also wait for this launch, not just the transfer. Gate on the
-        # edge-count output — the state outputs become DONATED inputs of
-        # the next round (sharded cohorts), which block_until_ready rejects
-        self._stager.note_consumer(edges)
-        if trace is not None:
-            now = trace.clock()
-            trace.add("launch", t_launch, now, cat="host",
-                      lanes=len(cohorts))
             # H2D overlap attribution: the super-batch transfer was
             # dispatched inside stage; only fencing it (sampled rounds
             # only) shows how far past the dispatch it actually landed
             jax.block_until_ready(superbatch)
-            trace.add("h2d", t_stage, trace.clock(), cat="device",
+            trace.add("h2d", staged.t0, trace.clock(), cat="device",
                       rows=len(rows))
         outs: dict[str, tgn.BatchOut] = {}
-        for c, out in zip(cohorts, outs_t):
-            c.state = out.state
-            for i, tid in enumerate(c.tids):
-                if tid in host:
-                    outs[tid] = self._slice_out(out, i, host[tid][0].shape[0])
+        with span("session.outputs"):
+            for c, out in zip(cohorts, outs_t):
+                c.state = out.state
+                for i, tid in enumerate(c.tids):
+                    if tid in host:
+                        outs[tid] = self._slice_out(out, i,
+                                                    host[tid][0].shape[0])
         return outs, edges
 
     def lower_round(self, width: int):
@@ -1061,59 +1060,65 @@ class SessionManager:
         Steps are fully asynchronous: nothing here blocks on the device,
         so staging round k+1 overlaps the compute of round k. ``sync()``
         (or ``summary()``, which calls it) drains the fleet.
+
+        The step and its phases are profiler spans (``obs.span``):
+        ``session.step`` (argument ``round``, the round's index) holds
+        ``session.stage`` (with ``session.stage_wait``, the stager's reuse
+        gate), ``session.dispatch`` and ``session.outputs``.
         """
-        unknown = set(batches) - set(self._tenant_cohort)
-        if unknown:
-            raise KeyError(f"unknown tenants {sorted(unknown)}; "
-                           f"registered: {sorted(self._tenant_cohort)}")
-        if self._faults is not None:
-            # chaos-only injection hook: one attribute test when unarmed
-            batches = self._faults.on_round(self, batches)
-        if self._quarantined:
-            # quarantined traffic is dropped; the sick lane slot idle-
-            # masks below (valid=False), a bitwise no-op on its state
-            batches = {t: b for t, b in batches.items()
-                       if t not in self._quarantined}
-        trace = None
-        if self.tracer is not None and batches:
-            # sampled-trace gate: on unsampled rounds ``trace`` stays
-            # None and the round dispatches fence-free, preserving the
-            # async pipeline (and the pending edge scalars) untouched
-            trace = self.tracer if self.tracer.sample_round() else None
-        t0 = time.perf_counter()
-        if self._faults is not None:
-            self._faults.before_launch(self)   # may raise KernelFault
-        if not batches:
-            outs, edges, launches = {}, 0, 0
-        elif self.coalesce and not self._device_staged(batches):
-            outs, edges = self._coalesced_round(batches, trace=trace)
-            launches = 1
-        else:
-            outs, edges, launches = self._percohort_round(batches)
-        dt = time.perf_counter() - t0
-        self._drained = None
-        self.metrics.append({
-            "t0": t0, "latency_s": dt, "edges": edges,
-            "launches": launches, "tenants_active": len(outs),
-            "tids": tuple(batches)})
-        self.obs.counter("session.rounds").inc()
-        self.obs.counter("session.launches").inc(launches)
-        for tid, b in batches.items():
-            rows = (b.src if isinstance(b, EdgeBatch) else b[0]).shape[0]
-            ts = self._tenant_stats[tid]
-            ts["rounds"] += 1
-            ts["rows"] += int(rows)
-            ts["last_flush_t"] = t0
-        if trace is not None:
-            # drain fence, sampled rounds ONLY: wait for this round's
-            # commits so its device time is attributed to a span
-            t_drain = trace.clock()
-            jax.block_until_ready(tuple(c.state
-                                        for c in self._cohorts.values()
-                                        if c.state is not None))
-            trace.add("drain", t_drain, trace.clock(), cat="device",
-                      round=len(self.metrics) - 1)
-        return outs
+        with span("session.step", round=len(self.metrics)):
+            unknown = set(batches) - set(self._tenant_cohort)
+            if unknown:
+                raise KeyError(f"unknown tenants {sorted(unknown)}; "
+                               f"registered: {sorted(self._tenant_cohort)}")
+            if self._faults is not None:
+                # chaos-only injection hook: one attribute test when unarmed
+                batches = self._faults.on_round(self, batches)
+            if self._quarantined:
+                # quarantined traffic is dropped; the sick lane slot idle-
+                # masks below (valid=False), a bitwise no-op on its state
+                batches = {t: b for t, b in batches.items()
+                           if t not in self._quarantined}
+            trace = None
+            if self.tracer is not None and batches:
+                # sampled-trace gate: on unsampled rounds ``trace`` stays
+                # None and the round dispatches fence-free, preserving the
+                # async pipeline (and the pending edge scalars) untouched
+                trace = self.tracer if self.tracer.sample_round() else None
+            t0 = time.perf_counter()
+            if self._faults is not None:
+                self._faults.before_launch(self)   # may raise KernelFault
+            if not batches:
+                outs, edges, launches = {}, 0, 0
+            elif self.coalesce and not self._device_staged(batches):
+                outs, edges = self._coalesced_round(batches, trace=trace)
+                launches = 1
+            else:
+                outs, edges, launches = self._percohort_round(batches)
+            dt = time.perf_counter() - t0
+            self._drained = None
+            self.metrics.append({
+                "t0": t0, "latency_s": dt, "edges": edges,
+                "launches": launches, "tenants_active": len(outs),
+                "tids": tuple(batches)})
+            self.obs.counter("session.rounds").inc()
+            self.obs.counter("session.launches").inc(launches)
+            for tid, b in batches.items():
+                rows = (b.src if isinstance(b, EdgeBatch) else b[0]).shape[0]
+                ts = self._tenant_stats[tid]
+                ts["rounds"] += 1
+                ts["rows"] += int(rows)
+                ts["last_flush_t"] = t0
+            if trace is not None:
+                # drain fence, sampled rounds ONLY: wait for this round's
+                # commits so its device time is attributed to a span
+                t_drain = trace.clock()
+                jax.block_until_ready(tuple(c.state
+                                            for c in self._cohorts.values()
+                                            if c.state is not None))
+                trace.add("drain", t_drain, trace.clock(), cat="device",
+                          round=len(self.metrics) - 1)
+            return outs
 
     def sync(self) -> None:
         """Drain the fleet: wait until every dispatched round's commits

@@ -3,16 +3,21 @@
 Pure-host tests: metric math (streaming histogram quantiles vs a sorted
 list, merge associativity, the defined empty case), registry semantics
 (get-or-create, one-type-per-name, atomic snapshot), tracer sampling +
-Chrome/Perfetto export shape, and SLO burn arithmetic.
+Chrome/Perfetto export shape, SLO burn arithmetic, and the profiler span
+helper (``obs.span``) — including one profiler round trip through a tiny
+``SessionManager`` on the CPU.
 """
 from __future__ import annotations
 
+import glob
 import json
+import os
 
 import pytest
 
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry,
-                       RoundTracer, SLOTracker, Span)
+                       RoundTracer, SLOTracker, Span, span)
+from repro.obs import trace as obs_trace
 
 
 # --------------------------------------------------------------- metrics
@@ -147,7 +152,7 @@ def test_tracer_would_sample_peeks_without_advancing():
 def test_tracer_spans_and_bound():
     clk = _FakeClock()
     tr = RoundTracer(clock=clk, max_spans=2)
-    with tr.span("stage", cat="host", rows=3):
+    with span("session.stage", tr, rows=3):
         clk.t += 0.5
     tr.add("launch", 100.5, 100.6, cat="host")
     tr.add("overflow", 0, 1)
@@ -190,6 +195,161 @@ def test_span_as_dict():
     assert s.dur == pytest.approx(0.5)
     assert s.as_dict() == {"name": "launch", "cat": "host", "t0": 2.0,
                            "t1": 2.5, "dur": 0.5, "lanes": 2}
+
+
+# ------------------------------------------------------- profiler spans
+def test_span_records_into_tracer_on_sampled_rounds_only():
+    clk = _FakeClock()
+    tr = RoundTracer(clock=clk, sample_every=2)
+    for _ in range(4):
+        trace = tr if tr.sample_round() else None
+        with span("session.stage", trace, rows=3, width=8):
+            clk.t += 0.5
+        with span("session.dispatch", trace, lanes=2):
+            clk.t += 0.25
+        with span("session.outputs", trace):     # profiler-only span
+            clk.t += 1.0
+    assert [s.name for s in tr.spans] == ["stage", "launch"] * 2
+    stage, launch = tr.spans[:2]
+    assert stage.cat == "host" and stage.dur == pytest.approx(0.5)
+    assert stage.args == {"rows": 3, "width": 8}
+    assert launch.args == {"lanes": 2}
+    assert launch.dur == pytest.approx(0.25)
+    # recorded spans start where the tracer clock stood at entry
+    assert launch.t0 == pytest.approx(stage.t1)
+
+
+def test_span_names_map_to_the_tracer_taxonomy():
+    assert obs_trace.RECORDED_AS == {
+        "session.stage": ("stage", "host"),
+        "session.dispatch": ("launch", "host"),
+        "frontend.flush": ("flush", "frontend")}
+    clk = _FakeClock()
+    tr = RoundTracer(clock=clk)
+    with span("frontend.flush", tr) as fl:
+        clk.t += 0.125
+        fl.args["tenants"] = 5               # added inside the block
+    (s,) = tr.spans
+    assert (s.name, s.cat, s.args) == ("flush", "frontend", {"tenants": 5})
+    assert fl.t0 == pytest.approx(100.0)
+
+
+def test_span_without_tracer_is_one_annotation_and_never_fences(monkeypatch):
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    def fence(*_a, **_k):
+        raise AssertionError("span fenced the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", fence)
+    for name in ("session.step", "session.stage", "session.stage_wait",
+                 "session.dispatch", "session.outputs", "frontend.flush"):
+        ann = span(name, None, round=3)
+        assert type(ann) is TraceAnnotation
+        with ann:
+            pass
+    # a sampled round records on the tracer's clock, still without a fence
+    tr = RoundTracer(clock=_FakeClock())
+    with span("session.dispatch", tr if tr.sample_round() else None):
+        pass
+    assert [s.name for s in tr.spans] == ["launch"]
+
+
+def _tiny_session(n_tenants=2, f=8):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import pipeline as pl, tgn
+    from repro.data import temporal_graph as tgd
+    from repro.serving.session import SessionManager
+
+    g = tgd.wikipedia_like(n_edges=200)
+    cfg = pl.variant_config("teacher", n_nodes=g.cfg.n_nodes,
+                            n_edges=g.n_edges, f_edge=172, f_mem=f,
+                            f_time=f, f_emb=f, m_r=4)
+    params = tgn.init_params(jax.random.key(0), cfg)
+    mgr = SessionManager(params, jnp.asarray(g.edge_feats), model=cfg,
+                         use_kernels=False)
+    tids = [mgr.add_tenant() for _ in range(n_tenants)]
+
+    def batches(r, b=16):
+        lo = r * b
+        out = {}
+        for i, t in enumerate(tids):
+            sl = slice(lo + 40 * i, lo + 40 * i + b)
+            eid = np.arange(sl.start, sl.stop, dtype=np.int32)
+            out[t] = (g.src[sl], g.dst[sl], eid, g.ts[sl], None)
+        return out
+    return mgr, batches
+
+
+def test_unsampled_session_rounds_never_fence(monkeypatch):
+    """Two rounds with no tracer attached: the span sites and the round
+    dispatch call no ``jax.block_until_ready`` (the stager's reuse gate
+    first waits in the third round, on the first round's set)."""
+    import jax
+    mgr, batches = _tiny_session()
+    mgr.step(batches(0))                     # compile outside the patch
+    mgr.sync()
+    mgr._stager.drain()
+
+    def fence(*_a, **_k):
+        raise AssertionError("an unsampled round fenced the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", fence)
+    mgr.step(batches(1))
+    mgr.step(batches(2))
+    monkeypatch.undo()
+    mgr.sync()
+
+
+def test_profiler_round_trip_nests_session_spans(tmp_path):
+    """The session's spans land in a real profiler trace, on the host
+    plane, nested as step > stage (> stage_wait) / dispatch / outputs,
+    once per round, and the step span carries the round's index."""
+    import jax
+    from jax.profiler import ProfileData
+    mgr, batches = _tiny_session()
+    for r in range(2):                       # compile and fill both sets
+        mgr.step(batches(r))
+    mgr.sync()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for r in range(2, 5):
+            mgr.step(batches(r))
+        mgr.sync()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                events.extend((ev.name, ev.start_ns, ev.end_ns,
+                               dict(ev.stats)) for ev in line.events
+                              if ev.name.startswith("session."))
+    steps = sorted((e for e in events if e[0] == "session.step"),
+                   key=lambda e: e[1])
+    assert [e[3].get("round") for e in steps] == [2, 3, 4]
+
+    def inside(name, outer):
+        return [e for e in events if e[0] == name
+                and outer[1] <= e[1] and e[2] <= outer[2]]
+
+    for st in steps:
+        (stage,) = inside("session.stage", st)
+        (dispatch,) = inside("session.dispatch", st)
+        (outputs,) = inside("session.outputs", st)
+        assert stage[2] <= dispatch[1] and dispatch[2] <= outputs[1]
+        # the stager waits on the set of the round before last, the one
+        # place the round waits on the device; sync() drained both sets,
+        # so the third round after it is the first to wait
+        waits = inside("session.stage_wait", stage)
+        assert len(waits) == (st[3]["round"] >= 4)
 
 
 # ------------------------------------------------------------------- slo

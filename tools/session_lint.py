@@ -37,6 +37,15 @@ silent — everything still computes the right numbers, just slower):
    accuracy can never quietly become an every-round drain.
    (``SessionManager.step`` keeps its by-design round-wall
    ``perf_counter`` pair — only its fences are guarded.)
+   The profiler span helper ``obs.span`` is accepted in ``step``,
+   ``_coalesced_round`` and ``_HostStager.stage``; the helper itself
+   (``span``, ``_Recorded``) must hold no fence or ``perf_counter`` at
+   all, and neither may the body of any ``with span("session.…")`` /
+   ``span("frontend.…")`` site outside the sampled-trace gate, wherever
+   it sits. Two spans hold one probe by design: ``session.stage_wait``
+   IS the stager's reuse gate (its ``block_until_ready`` is allowed
+   there and nowhere else in ``_HostStager.stage``), and
+   ``session.step`` holds ``step``'s round-wall ``perf_counter`` pair.
 
 4. Fault-injection hooks must stay NO-OP gated: every call to a
    ``FaultInjector`` hook (``on_round`` / ``before_launch`` /
@@ -100,9 +109,10 @@ GUARDED = {
 FENCES = {"block_until_ready", "perf_counter"}
 
 #: file -> ((scope, function, banned fence names), ...). Same scope
-#: conventions as GUARDED. ``_HostStager.stage``'s transfer wait and
-#: ``SessionManager.sync()`` are exempt by design (staging IS the
-#: transfer; sync is the explicit drain the callers opt into).
+#: conventions as GUARDED. ``SessionManager.sync()`` is exempt by design
+#: (the explicit drain the callers opt into); ``_HostStager.stage``'s
+#: reuse-gate wait is allowed only inside its ``session.stage_wait``
+#: span (SPAN_ALLOWS).
 FENCE_GUARDED = {
     os.path.join("src", "repro", "serving", "session.py"): (
         # step()'s round-wall perf_counter pair is the metrics contract;
@@ -110,12 +120,28 @@ FENCE_GUARDED = {
         ("SessionManager", "step", {"block_until_ready"}),
         ("SessionManager", "_coalesced_round", FENCES),
         ("SessionManager", "_percohort_round", FENCES),
+        ("_HostStager", "stage", FENCES),
     ),
     os.path.join("src", "repro", "core", "pipeline.py"): (
         ("CoalescedRound", "__call__", FENCES),
         ("*", "round_fn", FENCES),
     ),
+    # the profiler span helper runs on every round: never a fence
+    os.path.join("src", "repro", "obs", "trace.py"): (
+        (None, "span", FENCES),
+        ("_Recorded", "__enter__", FENCES),
+        ("_Recorded", "__exit__", FENCES),
+    ),
 }
+
+#: span-name prefixes whose ``with span(...)`` sites are checked for
+#: fences wherever they sit (rule 3), and the files they sit in.
+SPAN_PREFIXES = ("session.", "frontend.")
+SPAN_FILES = (os.path.join("src", "repro", "serving", "session.py"),
+              os.path.join("src", "repro", "serving", "frontend.py"))
+#: the probes a span's body may hold by design (rule 3).
+SPAN_ALLOWS = {"session.stage_wait": {"block_until_ready"},
+               "session.step": {"perf_counter"}}
 
 #: FaultInjector hook methods whose call must be fault-gated (rule 4).
 FAULT_HOOKS = {"on_round", "before_launch", "on_ingest",
@@ -205,26 +231,67 @@ def _is_trace_gate(test: ast.expr) -> bool:
     return False
 
 
-def _fence_violations(fn: ast.FunctionDef, banned: set) -> list:
+def _span_names(node: ast.With) -> list:
+    """The names of the ``span("...")`` calls a ``with`` opens."""
+    out = []
+    for item in node.items:
+        call = item.context_expr
+        if (isinstance(call, ast.Call) and call.args
+                and isinstance(call.args[0], ast.Constant)
+                and isinstance(call.args[0].value, str)):
+            f = call.func
+            name = (f.attr if isinstance(f, ast.Attribute)
+                    else f.id if isinstance(f, ast.Name) else None)
+            if name == "span":
+                out.append(call.args[0].value)
+    return out
+
+
+def _fence_violations(fn: ast.AST, banned: set) -> list:
     """Fence/timing calls reachable UNCONDITIONALLY (i.e. outside every
-    sampled-trace-gated ``if`` body) inside ``fn``."""
+    sampled-trace-gated ``if`` body) inside ``fn``; inside a ``with
+    span(name)`` block, the probes ``SPAN_ALLOWS[name]`` are allowed."""
     out = []
 
-    def visit(node, gated):
+    def visit(node, gated, banned):
+        if isinstance(node, ast.If) and _is_trace_gate(node.test):
+            for b in node.body:
+                visit(b, True, banned)
+            for b in node.orelse:
+                visit(b, gated, banned)
+            return
+        if isinstance(node, ast.With):
+            inner = set(banned)
+            for name in _span_names(node):
+                inner -= SPAN_ALLOWS.get(name, set())
+            for item in node.items:
+                visit(item, gated, banned)
+            for b in node.body:
+                visit(b, gated, inner)
+            return
+        ident = (node.attr if isinstance(node, ast.Attribute)
+                 else node.id if isinstance(node, ast.Name) else None)
+        if not gated and ident in banned:
+            out.append((node.lineno, ident))
         for sub in ast.iter_child_nodes(node):
-            if isinstance(sub, ast.If) and _is_trace_gate(sub.test):
-                for b in sub.body:
-                    visit(b, True)
-                for b in sub.orelse:
-                    visit(b, gated)
-                continue
-            ident = (sub.attr if isinstance(sub, ast.Attribute)
-                     else sub.id if isinstance(sub, ast.Name) else None)
-            if not gated and ident in banned:
-                out.append((sub.lineno, ident))
-            visit(sub, gated)
+            visit(sub, gated, banned)
 
-    visit(fn, False)
+    visit(fn, False, banned)
+    return out
+
+
+def _span_site_violations(tree: ast.AST) -> list:
+    """``(lineno, span name, probe)`` for each fence/timing call outside
+    the sampled-trace gate in the body of a ``with span(...)`` whose
+    name starts with one of SPAN_PREFIXES."""
+    out = []
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.With):
+            continue
+        names = [n for n in _span_names(node) if n.startswith(SPAN_PREFIXES)]
+        if names:
+            out.extend((lineno, names[0], what)
+                       for lineno, what in _fence_violations(node, FENCES))
     return out
 
 
@@ -352,6 +419,19 @@ def check_fences(relpath: str, guards) -> tuple[int, list]:
     return checked, errors
 
 
+def check_span_sites(relpath: str) -> tuple[int, list]:
+    with open(os.path.join(REPO, relpath)) as f:
+        tree = ast.parse(f.read(), relpath)
+    base = os.path.basename(relpath)
+    sites = sum(1 for n in ast.walk(tree) if isinstance(n, ast.With)
+                and any(s.startswith(SPAN_PREFIXES) for s in _span_names(n)))
+    errors = [f"{base}:{lineno}: unconditional {what} in span {name!r} — "
+              "a profiler span site runs on every round; it only fences/"
+              "times inside the sampled-trace gate (if trace ...:)"
+              for lineno, name, what in _span_site_violations(tree)]
+    return sites, errors
+
+
 def check_faults(relpath: str, guards) -> tuple[int, list]:
     with open(os.path.join(REPO, relpath)) as f:
         tree = ast.parse(f.read(), relpath)
@@ -411,6 +491,10 @@ def main() -> int:
         c, errs = check_fences(relpath, guards)
         checked += c
         errors.extend(errs)
+    for relpath in SPAN_FILES:
+        c, errs = check_span_sites(relpath)
+        checked += c
+        errors.extend(errs)
     for relpath, guards in FAULT_GUARDED.items():
         c, errs = check_faults(relpath, guards)
         checked += c
@@ -421,7 +505,8 @@ def main() -> int:
         errors.extend(errs)
     for e in errors:
         print(f"session-lint: {e}", file=sys.stderr)
-    print(f"session-lint: {checked} hot-path functions checked, "
+    print(f"session-lint: {checked} hot-path functions and span sites "
+          f"checked, "
           f"{len(errors)} error(s)")
     return 1 if errors else 0
 
