@@ -204,6 +204,35 @@ def _idle_dev(B: int) -> tuple:
     return (zi, zi, zi, jnp.zeros((B,), jnp.float32), jnp.zeros((B,), bool))
 
 
+#: the per-tenant leaves of a round's ``BatchOut``: every field but
+#: ``state``, which stays in the session (``step`` hands out ``state=None``)
+_OUT_LEAVES = tgn.BatchOut._fields[1:]
+
+
+@jax.jit
+def _split_out(leaves: tuple) -> tuple:
+    """Every slot's unbatched ``_OUT_LEAVES`` of a cohort's stacked round
+    output, as ONE compiled program: ``out[i]`` is slot ``i``'s five
+    leaves. The cache key is the stacked shapes alone — fixed by the
+    cohort's capacity and round width, as the round program is — so it
+    compiles once per layout, whichever tenants submitted (eager per-slot
+    indexing would dispatch five programs per tenant per round)."""
+    return tuple(tuple(x[i] for x in leaves)
+                 for i in range(leaves[0].shape[0]))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _trim_out(b: int, leaves: tuple) -> tuple:
+    """One slot's ``_OUT_LEAVES`` cut from the cohort's round width ``B``
+    back to the tenant's own ``b`` rows: rows ``[0:b]``, and ``[0:b]`` +
+    ``[B:B+b]`` of the 2B-row distill views (concat([src rows, dst
+    rows])). One compiled program per ``(B, b)``."""
+    B = leaves[0].shape[0]
+    src, dst, *two = leaves
+    return (src[:b], dst[:b],
+            *(jnp.concatenate([x[:b], x[B:B + b]]) for x in two))
+
+
 #: the parameter-set name every tenant serves on unless it names another.
 DEFAULT_PARAMS = "default"
 
@@ -991,12 +1020,26 @@ class SessionManager:
                       rows=len(rows))
         outs: dict[str, tgn.BatchOut] = {}
         with span("session.outputs"):
+            splits = self.obs.counter("session.output_splits")
+            trims = self.obs.counter("session.output_trims")
             for c, out in zip(cohorts, outs_t):
                 c.state = out.state
-                for i, tid in enumerate(c.tids):
-                    if tid in host:
-                        outs[tid] = self._slice_out(out, i,
-                                                    host[tid][0].shape[0])
+                mine = [(i, tid) for i, tid in enumerate(c.tids)
+                        if tid in host]
+                if not mine:
+                    continue
+                # one compiled split per cohort; picking the submitted
+                # slots out of its result is host-side tuple indexing
+                slots = _split_out(tuple(getattr(out, f)
+                                         for f in _OUT_LEAVES))
+                splits.inc()
+                for i, tid in mine:
+                    leaves = slots[i]
+                    b = host[tid][0].shape[0]
+                    if b < widths[id(c)]:
+                        leaves = _trim_out(b, leaves)
+                        trims.inc()
+                    outs[tid] = tgn.BatchOut(None, *leaves)
         return outs, edges
 
     def lower_round(self, width: int):
@@ -1055,7 +1098,11 @@ class SessionManager:
         with ``coalesce=False`` each submitted cohort launches separately.
         Returns ``{tid: BatchOut}`` for the submitted tenants with
         ``state=None`` — per-tenant states are committed in place; read
-        them via ``state_of``.
+        them via ``state_of``. Coalesced, the outputs come from ONE
+        compiled split per submitting cohort (``_split_out``), plus one
+        compiled trim (``_trim_out``) for each tenant that submitted
+        fewer rows than its cohort's round width; the registry counts
+        both (``session.output_splits``, ``session.output_trims``).
 
         Steps are fully asynchronous: nothing here blocks on the device,
         so staging round k+1 overlaps the compute of round k. ``sync()``

@@ -205,6 +205,12 @@ def test_sharded_coalesced_matches_percohort_bitwise(small_graph, mesh):
             np.testing.assert_array_equal(
                 np.asarray(o1[t1[i]].emb_src), np.asarray(o2[t2[i]].emb_src),
                 err_msg=f"round {r} tenant {i}")
+            # the coalesced round's compiled split places each tenant's
+            # outputs where the per-cohort baseline's eager cut does
+            for f in ("emb_src", "emb_dst", "attn_logits", "nbr_valid",
+                      "nbr_dt"):
+                assert (getattr(o1[t1[i]], f).sharding
+                        == getattr(o2[t2[i]], f).sharding), (r, i, f)
     for a, b in zip(t1, t2):
         _assert_state_equal(m1.state_of(a), m2.state_of(b), msg=a)
     # the super-batch row space covers every cohort's mesh capacity

@@ -11,6 +11,7 @@ from repro.core import pipeline as pl, stages, tgn
 from repro.data import stream as stream_mod
 from repro.data import temporal_graph as tgd
 from repro.serving.engine import StreamingEngine
+from repro.serving import session as session_mod
 from repro.serving.session import SessionManager
 
 
@@ -474,6 +475,86 @@ def test_mixed_kernel_tier_fleet_replays_bitwise(small_graph):
                             msg=f"lane {i} coalesced-vs-percohort")
         _assert_state_equal(m1.state_of(t1[i]), sm.state_of(st),
                             msg=f"lane {i} coalesced-vs-solo")
+
+
+def test_coalesced_outputs_come_from_one_split_per_cohort(small_graph,
+                                                          monkeypatch):
+    """The coalesced round hands every submitted tenant exactly what
+    ``_slice_out`` cuts from the same stacked round — bitwise, in all
+    five leaves, ``state`` left out — from ONE compiled split per
+    submitting cohort, plus one compiled trim per tenant that submitted
+    fewer rows than its cohort's round width. Fused, staged and
+    reservoir cohorts of two tenants each, through a ragged round and an
+    idle lane; at unchanged widths the split never recompiles."""
+    g = small_graph
+    dims = _dims(g, f=8)
+    cfg = pl.variant_config("sat+lut+np4", **dims)
+    params = tgn.init_params(jax.random.key(12), cfg)
+    lanes = ((None, "fused"), (None, "staged"),
+             ("sat+lut+np4+reservoir", "fused"))
+    mgr = SessionManager(params, jnp.asarray(g.edge_feats), model=cfg,
+                         use_kernels="staged")
+    tids = [mgr.add_tenant(v, use_kernels=t) for v, t in lanes
+            for _ in range(2)]             # tenants 2k, 2k+1 share lane k
+    assert len(mgr.describe()) == 3
+
+    stacked = []
+    launch = pl.CoalescedRound.__call__
+
+    def recording(self, *a, **kw):
+        res = launch(self, *a, **kw)
+        stacked.append(res[0])
+        return res
+
+    monkeypatch.setattr(pl.CoalescedRound, "__call__", recording)
+    feeds = [list(_tenant_stream(g, i, batch=30, rounds=6))
+             for i in range(len(tids))]
+    # round -> {tenant: rows}; absent tenants idle that round
+    plan = ({i: 30 for i in range(6)},
+            {0: 18, 1: 30, 2: 18, 3: 18, 4: 30, 5: 8},   # ragged
+            {0: 30, 4: 30, 5: 30},                       # staged lane idle
+            *({i: 30 for i in range(6)},) * 3)           # unchanged widths
+    counters = ("session.output_splits", "session.output_trims")
+    cache = [session_mod._split_out._cache_size()]
+    for r, rows in enumerate(plan):
+        batches = {}
+        for i, w in rows.items():
+            b = feeds[i][r]
+            batches[tids[i]] = stream_mod.EdgeBatch(
+                src=b.src[:w], dst=b.dst[:w], eid=b.eid[:w], ts=b.ts[:w],
+                valid=b.valid[:w], neg_dst=b.neg_dst[:w])
+        before = mgr.obs.snapshot()
+        calls = mgr._coalesced.calls if mgr._coalesced is not None else 0
+        outs = mgr.step(batches)
+        after = mgr.obs.snapshot()
+        cache.append(session_mod._split_out._cache_size())
+        assert mgr._coalesced.calls == calls + 1   # still ONE launch
+        assert set(outs) == set(batches)
+        submitting, ragged = 0, 0
+        for c, out in zip(mgr._cohorts.values(), stacked[-1]):
+            mine = [t for t in c.tids if t in batches]
+            submitting += bool(mine)
+            B = max((batches[t].src.shape[0] for t in mine), default=0)
+            for t in mine:
+                b = batches[t].src.shape[0]
+                ragged += b < B
+                want = SessionManager._slice_out(out, c.tids.index(t), b)
+                assert outs[t].state is None
+                for f in session_mod._OUT_LEAVES:
+                    got = getattr(outs[t], f)
+                    assert got.shape == getattr(want, f).shape
+                    np.testing.assert_array_equal(
+                        np.asarray(got), np.asarray(getattr(want, f)),
+                        err_msg=f"round {r} {t} {f}")
+        assert (submitting, ragged) == ((3, 2) if r == 1 else
+                                        (2, 0) if r == 2 else (3, 0))
+        assert [after.get(n, 0) - before.get(n, 0) for n in counters] == [
+            submitting, ragged], f"round {r}"
+    # the three cohorts' stacked outputs share one shape a round: at most
+    # one split compile each for the first two rounds' widths (30, then
+    # the staged lane's 18), none for the later rounds at seen widths
+    compiles = [b - a for a, b in zip(cache, cache[1:])]
+    assert max(compiles[:2]) <= 1 and compiles[2:] == [0] * 4, compiles
 
 
 # ---------------------------------------------------------------------------

@@ -64,3 +64,52 @@ def test_helper_may_not_fence(lint):
     (fn,) = ast.parse(src).body
     assert [w for _l, w in lint._fence_violations(fn, lint.FENCES)] == [
         "block_until_ready"]
+
+
+# rule 6: ``_coalesced_round``'s loops hand out per-tenant outputs from
+# one compiled split per cohort, never one eager program per tenant
+_ROUND = "def _coalesced_round(self, batches):\n{}"
+
+
+@pytest.mark.parametrize("body, found", [
+    # the loop as it was: one _slice_out (five eager index ops) a tenant
+    ("    for c, out in zip(cohorts, outs_t):\n"
+     "        c.state = out.state\n"
+     "        for i, tid in enumerate(c.tids):\n"
+     "            if tid in host:\n"
+     "                outs[tid] = self._slice_out(out, i, b)\n",
+     ["_slice_out"]),
+    # the same cut inlined: a subscript of a round output's leaf
+    ("    for c, out in zip(cohorts, outs_t):\n"
+     "        outs.update({t: BatchOut(None, out.emb_src[i], out.nbr_dt[i])\n"
+     "                     for i, t in enumerate(c.tids)})\n",
+     ["subscript of .emb_src", "subscript of .nbr_dt"]),
+    # the loop as it is: one split a cohort, host-side picks of its slots
+    ("    for c, out in zip(cohorts, outs_t):\n"
+     "        c.state = out.state\n"
+     "        slots = _split_out(tuple(getattr(out, f)\n"
+     "                                 for f in _OUT_LEAVES))\n"
+     "        for i, tid in mine:\n"
+     "            leaves = slots[i]\n"
+     "            if b < widths[id(c)]:\n"
+     "                leaves = _trim_out(b, leaves)\n"
+     "            outs[tid] = tgn.BatchOut(None, *leaves)\n",
+     []),
+    # outside a loop a subscript is no per-tenant cut
+    ("    first = out.emb_src[0]\n", []),
+])
+def test_output_rule(lint, body, found):
+    (fn,) = ast.parse(_ROUND.format(body)).body
+    assert [w for _l, w in lint._output_violations(fn)] == found
+
+
+def test_output_rule_guards_the_round(lint, tmp_path, monkeypatch):
+    """The rule is wired to ``SessionManager._coalesced_round`` and fails
+    the run when the guarded function disappears."""
+    rel = next(iter(lint.OUTPUT_GUARDED))
+    assert lint.check_outputs(rel, lint.OUTPUT_GUARDED[rel]) == (1, [])
+    (tmp_path / "s.py").write_text("class SessionManager:\n    pass\n")
+    monkeypatch.setattr(lint, "REPO", str(tmp_path))
+    checked, errors = lint.check_outputs(
+        "s.py", (("SessionManager", "_coalesced_round"),))
+    assert checked == 0 and "not found" in errors[0]

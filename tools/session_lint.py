@@ -64,6 +64,14 @@ silent — everything still computes the right numbers, just slower):
    test per event and NO disk IO (docs/ROBUSTNESS.md, "Recovery
    semantics").
 
+6. The coalesced round hands each tenant its outputs from ONE compiled
+   split per cohort (``session._split_out``): inside the loops of
+   ``SessionManager._coalesced_round`` no call to ``_slice_out`` and no
+   subscript of a round output's leaf (``out.emb_src[i]``, ...). Each
+   is an eager device program per tenant per round; at a fleet's width
+   those dispatches, not the device, set the round's rate. The
+   per-cohort baseline and ``peek`` keep ``_slice_out`` by design.
+
 Exits non-zero listing every violation; also fails if a guarded function
 disappears (a rename must update this guard, not silently skip it).
 """
@@ -176,6 +184,22 @@ JOURNAL_GUARDED = {
         ("ServingFrontend", "pump"),
     ),
 }
+
+#: ``BatchOut`` leaves whose subscript in a loop cuts per-tenant outputs
+#: one eager program at a time (rule 6).
+OUT_LEAVES = {"emb_src", "emb_dst", "attn_logits", "nbr_valid", "nbr_dt"}
+
+#: file -> ((scope, function), ...): round functions whose loops may not
+#: cut per-tenant outputs out of the stacked round (rule 6).
+OUTPUT_GUARDED = {
+    os.path.join("src", "repro", "serving", "session.py"): (
+        ("SessionManager", "_coalesced_round"),
+    ),
+}
+
+#: the nodes that repeat their body (rule 6).
+LOOPS = (ast.For, ast.While, ast.ListComp, ast.SetComp, ast.DictComp,
+         ast.GeneratorExp)
 
 
 def _functions(tree: ast.Module) -> dict:
@@ -366,6 +390,27 @@ def _journal_violations(fn: ast.FunctionDef) -> list:
     return out
 
 
+def _output_violations(fn: ast.AST) -> list:
+    """``_slice_out`` calls and subscripts of ``OUT_LEAVES`` attributes
+    inside any loop of ``fn``."""
+    out = set()
+    for loop in ast.walk(fn):
+        if not isinstance(loop, LOOPS):
+            continue
+        for node in ast.walk(loop):
+            if isinstance(node, ast.Call):
+                f = node.func
+                name = (f.attr if isinstance(f, ast.Attribute)
+                        else f.id if isinstance(f, ast.Name) else None)
+                if name == "_slice_out":
+                    out.add((node.lineno, "_slice_out"))
+            elif (isinstance(node, ast.Subscript)
+                  and isinstance(node.value, ast.Attribute)
+                  and node.value.attr in OUT_LEAVES):
+                out.add((node.lineno, f"subscript of .{node.value.attr}"))
+    return sorted(out)
+
+
 def check_file(relpath: str, guards) -> tuple[int, list]:
     with open(os.path.join(REPO, relpath)) as f:
         tree = ast.parse(f.read(), relpath)
@@ -481,6 +526,29 @@ def check_journal(relpath: str, guards) -> tuple[int, list]:
     return checked, errors
 
 
+def check_outputs(relpath: str, guards) -> tuple[int, list]:
+    with open(os.path.join(REPO, relpath)) as f:
+        tree = ast.parse(f.read(), relpath)
+    functions = _functions(tree)
+    errors, checked = [], 0
+    base = os.path.basename(relpath)
+    for scope, name in guards:
+        fn = functions.get((scope, name))
+        qual = f"{scope}.{name}"
+        if fn is None:
+            errors.append(f"guarded function {qual} not found in {base} — "
+                          "update tools/session_lint.py alongside the "
+                          "rename")
+            continue
+        checked += 1
+        for lineno, what in _output_violations(fn):
+            errors.append(
+                f"{base}:{lineno}: {what} in a loop of {qual} — per-tenant "
+                "outputs come from one compiled split per cohort "
+                "(_split_out), not an eager device program per tenant")
+    return checked, errors
+
+
 def main() -> int:
     errors, checked = [], 0
     for relpath, guards in GUARDED.items():
@@ -501,6 +569,10 @@ def main() -> int:
         errors.extend(errs)
     for relpath, guards in JOURNAL_GUARDED.items():
         c, errs = check_journal(relpath, guards)
+        checked += c
+        errors.extend(errs)
+    for relpath, guards in OUTPUT_GUARDED.items():
+        c, errs = check_outputs(relpath, guards)
         checked += c
         errors.extend(errs)
     for e in errors:
