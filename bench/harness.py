@@ -149,18 +149,19 @@ def build(cfg: dict, seed: int, graph, log):
     kinds = sorted({lane["params"] for lane in cfg["lanes"]})
     bounds = reference.lut_boundaries(stream.vertex_gaps(graph),
                                       w["lut_entries"])
+    f_feat = graph.shape.f_feat
     weights = reference.make_weights(seed, kinds, w, graph.shape.f_edge,
-                                     bounds)
+                                     bounds, f_feat)
     first = cfg["lanes"][0]
     dims = dict(n_nodes=graph.shape.n_nodes, n_edges=graph.n_edges,
-                f_edge=graph.shape.f_edge, f_mem=w["f_mem"],
+                f_edge=graph.shape.f_edge, f_feat=f_feat, f_mem=w["f_mem"],
                 f_time=w["f_time"], f_emb=w["f_emb"], m_r=w["m_r"],
                 n_heads=w["n_heads"], lut_entries=w["lut_entries"])
     model = pl.variant_config(first["variant"], **dims)
     fleet = FleetCapacity()
     mgr = SessionManager(weights[first["params"]],
-                         jnp.asarray(graph.edge_feats), model=model,
-                         reserve=fleet)
+                         jnp.asarray(graph.edge_feats),
+                         _node_feats(graph), model=model, reserve=fleet)
     for kind in kinds:
         if kind != first["params"]:
             mgr.register_params(kind, weights[kind])
@@ -178,13 +179,34 @@ def build(cfg: dict, seed: int, graph, log):
                        reservoir_tau=lane.get("reservoir_tau"))
         tids.append(tid)
     fleet.target = 0
-    spare = sum(v["capacity"] for v in mgr.describe().values()) - len(tids)
+    cohorts = mgr.describe()
+    spare = sum(v["capacity"] for v in cohorts.values()) - len(tids)
     if spare:
         raise RuntimeError(f"{spare} lane slots left spare")
+    _check_tiers(cfg, cohorts)
     log("lanes: " + ", ".join(f"{k} -> tier {v['tier']}, capacity "
                               f"{v['capacity']}"
-                              for k, v in mgr.describe().items()))
+                              for k, v in cohorts.items()))
     return mgr, weights, tids
+
+
+def _node_feats(graph):
+    """The graph's static node features on the device, or None."""
+    import jax.numpy as jnp
+    return None if graph.node_feats is None else jnp.asarray(graph.node_feats)
+
+
+def _check_tiers(cfg: dict, cohorts: dict) -> None:
+    """Refuse a configuration whose lane would run on another kernel tier
+    than it names (the session serves a variant outside a tier's coverage
+    on the next tier down): its cell would be measured under a false
+    name."""
+    runs = {tid: v["tier"] for v in cohorts.values() for tid in v["tenants"]}
+    for tid, lane in tenants(cfg):
+        if runs[tid] != lane["tier"]:
+            raise ValueError(
+                f"lane {lane['name']!r} names tier {lane['tier']!r}, but the "
+                f"session runs it on tier {runs[tid]!r}")
 
 
 class FleetCapacity:
@@ -543,18 +565,19 @@ def replay(cfg, graph, weights, rounds, tids, want, precision, log=None):
     for tid in tids:
         groups.setdefault(lane_key(lanes[tid]), []).append(tid)
     ef = jnp.asarray(graph.edge_feats)
+    nf = _node_feats(graph)
     mem_out, emb_out = {}, {r: {} for r in want}
     for key, gt in groups.items():
         lane = ref_lane(lanes[gt[0]], w)
         p = weights[lanes[gt[0]]["params"]]
 
-        def one(p, st, ef, b, _lane=lane):
-            return reference.step(p, _lane, st, ef, b, precision)
+        def one(p, st, ef, nf, b, _lane=lane):
+            return reference.step(p, _lane, st, ef, b, precision, nf)
 
         def margin(p, st, b, _lane=lane):
             return reference.selection_margin(p, _lane, st, b)
 
-        fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None, 0)),
+        fn = jax.jit(jax.vmap(one, in_axes=(None, 0, None, None, 0)),
                      donate_argnums=(1,))
         mfn = jax.jit(jax.vmap(margin, in_axes=(None, 0, 0)))
         st = jax.vmap(lambda _: reference.init_state(
@@ -577,7 +600,7 @@ def replay(cfg, graph, weights, rounds, tids, want, precision, log=None):
             batch = tuple(jnp.asarray(c) for c in cols)
             if r in want:
                 m = mfn(p, st, batch)
-            st, h = fn(p, st, ef, batch)
+            st, h = fn(p, st, ef, nf, batch)
             if log is not None and r < 3:
                 jax.block_until_ready(h)
                 log(f"reference round {r} done")
@@ -699,7 +722,7 @@ def run_cell(spec, cell_name, seed, seconds, trace, t_process, log,
                       "memory_peak_bytes": peak},
            "compiles_in_window": compiles.n, "rows_per_round": rows,
            "window_rounds": len(res["rounds"]) - res["n_warm"], **res}
-    rec["counts"] = _counts(cfg, tids, res, graph.shape.f_edge)
+    rec["counts"] = _counts(cfg, tids, res, graph.shape)
     if dev.platform != "cpu":
         rec["peaks"] = peaks.peaks(dev.device_kind)
     if trace:
@@ -760,8 +783,8 @@ def _measure(cfg, cell, traffic, seed, seconds, trace, trace_dir, t_process,
     t = time.monotonic()
     graph = stream.generate(stream.GraphShape(**cfg["graph"]), seed)
     log(f"graph: {graph.shape.n_nodes} vertices, {graph.n_edges} edges, "
-        f"{graph.shape.f_edge}-d edge features, made in "
-        f"{time.monotonic() - t} s")
+        f"{graph.shape.f_edge}-d edge features, {graph.shape.f_feat}-d node "
+        f"features, made in {time.monotonic() - t} s")
     loop = traffic["loop"]
     mgr, weights, tids = build(cfg, seed, graph, log)
     if fault is not None:
@@ -787,9 +810,10 @@ def fused_bound(c: dict, p: dict) -> str:
             >= c["fused_bytes"] / p["hbm_bytes_per_s"] else "bytes")
 
 
-def _counts(cfg, tids, res, f_edge) -> dict:
+def _counts(cfg, tids, res, shape) -> dict:
     """Required operations and bytes of the events applied in the window."""
     w = cfg["widths"]
+    f_edge, f_feat = shape.f_edge, shape.f_feat
     lanes = dict(tenants(cfg))
     per_tid = {t: 0 for t in tids}
     for rnd in res["rounds"][res["n_warm"]:]:
@@ -799,10 +823,13 @@ def _counts(cfg, tids, res, f_edge) -> dict:
     for t, n in per_tid.items():
         lane = lanes[t]
         k = lane.get("k", w["m_r"])
-        step_flops += n * counts.event_flops(lane["params"], k, w, f_edge)
+        step_flops += n * counts.event_flops(lane["params"], k, w, f_edge,
+                                             f_feat)
         if lane["tier"] == "fused":
             fused_rows += 2 * n
-            fused_flops += 2 * n * counts.fused_row_flops(k, w, f_edge)
-            fused_bytes += 2 * n * counts.fused_row_bytes(k, w, f_edge)
+            fused_flops += 2 * n * counts.fused_row_flops(k, w, f_edge,
+                                                          f_feat)
+            fused_bytes += 2 * n * counts.fused_row_bytes(k, w, f_edge,
+                                                          f_feat)
     return {"step_flops": step_flops, "fused_rows": fused_rows,
             "fused_flops": fused_flops, "fused_bytes": fused_bytes}

@@ -18,7 +18,13 @@ one chronological batch of edges at a time:
    the student scores each slot from its ``dt`` alone (``a + W_t
    log1p(dt)``), keeps the top k by score (or, with the reservoir sampler,
    by a time-decayed hash priority), and averages the projected rows of
-   those k by the softmax of their scores;
+   those k by the softmax of their scores. Where the vertices have static
+   features ``f``, the vertex's own representation is ``f' = s + W_s f +
+   b_s`` (the paper's Eq. 11) in place of its memory ``s``, where the
+   served model applies it (``core/attention.py``): in the teacher's
+   query input and in the output transform of both kinds. The neighbours'
+   keys and values take their memory alone, with no node features; that
+   is the reading of Eq. 11 checked here;
 4. the last instance of each vertex caches the new message;
 5. each edge enters both endpoints' ring buffers.
 
@@ -92,10 +98,14 @@ def _dense(key, shape, scale=None):
     return jax.random.normal(key, shape, jnp.float32) * scale
 
 
-def _params(key, kind: str, w: dict, f_edge: int, boundaries):
+def _params(key, kind: str, w: dict, f_edge: int, boundaries,
+            f_feat: int):
     """One parameter set, as the served model lays it out. ``kind`` is
     "student" (SAT attention, LUT time encoder) or "teacher" (two-head
-    attention, cosine time encoder)."""
+    attention, cosine time encoder). With static node features
+    (``f_feat > 0``) the attention's ``feat`` holds Eq. 11's ``w_s`` and
+    ``b_s``, drawn from a key of their own so that every other leaf is the
+    same as without them."""
     m, t, d, mr = w["f_mem"], w["f_time"], w["f_emb"], w["m_r"]
     f_mail = 2 * m + f_edge + t
     d_kv = m + f_edge + t
@@ -129,24 +139,30 @@ def _params(key, kind: str, w: dict, f_edge: int, boundaries):
                      "w_v": _dense(next(ks), (d_kv, d)), "b_v": small((d,)),
                      "w_out": _dense(next(ks), (m + d, d)),
                      "b_out": small((d,))}
+    if f_feat:
+        kw, kb = jax.random.split(jax.random.fold_in(key, 24))
+        p["attn"]["feat"] = {"w_s": _dense(kw, (f_feat, m)),
+                             "b_s": 0.1 * jax.random.normal(kb, (m,))}
     return p
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _make_weights(key, kinds: tuple, widths: tuple, f_edge: int, boundaries):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 5))
+def _make_weights(key, kinds: tuple, widths: tuple, f_edge: int, boundaries,
+                  f_feat: int):
     w = dict(widths)
     return {kind: _params(jax.random.fold_in(key, i), kind, w, f_edge,
-                          boundaries) for i, kind in enumerate(kinds)}
+                          boundaries, f_feat)
+            for i, kind in enumerate(kinds)}
 
 
 def make_weights(seed: int, kinds, widths: dict, f_edge: int,
-                 boundaries: np.ndarray) -> dict:
+                 boundaries: np.ndarray, f_feat: int = 0) -> dict:
     """Every parameter set a configuration serves, ``{kind: params}``,
     made on the device in one jitted call from ``seed``."""
     key = jax.random.key(seed % (2 ** 32))
     return _make_weights(key, tuple(sorted(set(kinds))),
                          tuple(sorted(widths.items())), int(f_edge),
-                         jnp.asarray(boundaries))
+                         jnp.asarray(boundaries), int(f_feat))
 
 
 # ---------------------------------------------------------------------------
@@ -234,7 +250,17 @@ def _neighbours(st, vids, t):
     return ids, eid, dt, valid
 
 
-def _embed_student(p, lane, st, ef, vids, t, precision):
+def _self_rep(p, st, nf, vids, precision):
+    """The vertex's own representation: its committed memory ``s``, or
+    with node features ``f`` Eq. 11's ``s + W_s f + b_s``."""
+    s = st["memory"][vids]
+    if nf is None:
+        return s
+    feat = p["attn"]["feat"]
+    return s + _mm(nf[vids], feat["w_s"], precision) + feat["b_s"]
+
+
+def _embed_student(p, lane, st, ef, nf, vids, t, precision):
     ids, eid, dt, valid = _neighbours(st, vids, t)
     a = p["attn"]
     score = a["a"] + _mm(jnp.log1p(dt), a["w_t"].T, precision)
@@ -253,18 +279,18 @@ def _embed_student(p, lane, st, ef, vids, t, precision):
     kv = jnp.concatenate([mem, edge, _phi(p, "student", take(dt))], axis=-1)
     v = _mm(kv, a["w_v"], precision) + a["b_v"]
     agg = _contract("bn,bnd->bd", w, v, precision)
-    h = _mm(jnp.concatenate([st["memory"][vids], agg], axis=-1), a["w_out"],
-            precision)
+    h = _mm(jnp.concatenate([_self_rep(p, st, nf, vids, precision), agg],
+                            axis=-1), a["w_out"], precision)
     return h + a["b_out"]
 
 
-def _embed_teacher(p, lane, st, ef, vids, t, precision):
+def _embed_teacher(p, lane, st, ef, nf, vids, t, precision):
     ids, eid, dt, valid = _neighbours(st, vids, t)
     a, n = p["attn"], vids.shape[0]
     heads = lane["heads"]
     mem = st["memory"][ids] * valid[..., None]
     edge = ef[eid] * valid[..., None]
-    s_self = st["memory"][vids]
+    s_self = _self_rep(p, st, nf, vids, precision)
     q_in = jnp.concatenate([s_self, _cos(p["time"], jnp.zeros_like(t))],
                            axis=-1)
     q = (_mm(q_in, a["w_q"], precision) + a["b_q"]).reshape(n, heads, -1)
@@ -281,10 +307,13 @@ def _embed_teacher(p, lane, st, ef, vids, t, precision):
     return h + a["b_out"]
 
 
-def step(p, lane: dict, st: dict, ef, batch, precision: str = "highest"):
+def step(p, lane: dict, st: dict, ef, batch, precision: str = "highest",
+         nf=None):
     """One chronological batch ``(src, dst, eid, ts, valid)`` of one
-    tenant. Returns ``(new state, embeddings (2B, f_emb))``: sources' rows
-    first, then destinations'. Rows with ``valid`` False change nothing."""
+    tenant, with edge features ``ef`` and static node features ``nf``
+    (None where the vertices have none). Returns ``(new state, embeddings
+    (2B, f_emb))``: sources' rows first, then destinations'. Rows with
+    ``valid`` False change nothing."""
     src, dst, eid, ts, valid = batch
     B = src.shape[0]
     V = st["memory"].shape[0]
@@ -317,7 +346,7 @@ def step(p, lane: dict, st: dict, ef, batch, precision: str = "highest"):
 
     # 3. embeddings on the committed memory
     embed = _embed_student if lane["kind"] == "student" else _embed_teacher
-    h = embed(p, lane, st, ef, vids, t, precision)
+    h = embed(p, lane, st, ef, nf, vids, t, precision)
 
     # 4. cache the new messages
     mem = st["memory"]

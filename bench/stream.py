@@ -6,7 +6,10 @@ child process (``bench/loadgen.py``) can use it without touching the chip.
 The graph generator is a copy of the program's synthetic stream (bipartite
 user->item interactions, Zipf endpoint popularity, Pareto inter-event
 gaps, 172-d edge features projected from endpoint latents), kept here so
-that the yardstick stays fixed while the program changes.
+that the yardstick stays fixed while the program changes. It also makes
+streams over one vertex population (``bipartite: false``, actor->actor
+events as in GDELT) and static node features (``f_feat > 0``), projected
+from the vertices' latents as edge features are from the endpoints'.
 
 A traffic mix is a JSON file of parameters (``bench/traffic/<name>.json``)
 that this one generator reads:
@@ -31,16 +34,25 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class GraphShape:
-    """Shape of a bipartite interaction stream (a JODIE-style data set)."""
+    """Shape of an interaction stream: bipartite user->item (a JODIE-style
+    data set), or with ``bipartite`` False events between the ``n_users``
+    vertices of one population (``n_items`` 0)."""
     n_users: int
     n_items: int
     n_edges: int
-    f_edge: int = 172
+    f_edge: int = 172          # edge features, 0 for none
     latent: int = 16
     zipf_a: float = 1.2        # endpoint popularity skew
     pareto_a: float = 1.1      # inter-event time tail
     t_scale: float = 60.0      # scale of inter-event seconds
     noise: float = 0.3
+    f_feat: int = 0            # static node features, 0 for none
+    bipartite: bool = True
+
+    def __post_init__(self):
+        if not self.bipartite and self.n_items:
+            raise ValueError("a stream over one population has n_items 0, "
+                             f"got {self.n_items}")
 
     @property
     def n_nodes(self) -> int:
@@ -49,12 +61,15 @@ class GraphShape:
 
 @dataclasses.dataclass
 class Graph:
-    """A chronological edge stream and its edge features (host NumPy)."""
+    """A chronological edge stream, its edge features and the vertices'
+    static features (host NumPy)."""
     src: np.ndarray         # (E,) int32 user ids in [0, n_users)
-    dst: np.ndarray         # (E,) int32 item ids in [n_users, n_nodes)
+    dst: np.ndarray         # (E,) int32 item ids in [n_users, n_nodes);
+    #                         over one population, in [0, n_users) too
     ts: np.ndarray          # (E,) float32, non-decreasing
     edge_feats: np.ndarray  # (E, f_edge) float32
     shape: GraphShape
+    node_feats: np.ndarray | None = None   # (n_nodes, f_feat) float32
 
     @property
     def n_edges(self) -> int:
@@ -69,27 +84,51 @@ def _zipf_choice(rng, n: int, size: int, a: float) -> np.ndarray:
 
 
 def generate(shape: GraphShape, seed: int) -> Graph:
-    """The seeded stream: the same ``(shape, seed)`` gives the same graph."""
+    """The seeded stream: the same ``(shape, seed)`` gives the same graph.
+    Node features come from a generator of their own, so a shape without
+    them draws exactly what it drew before they existed."""
     rng = np.random.RandomState(seed % (2 ** 32))
     U, I, E = shape.n_users, shape.n_items, shape.n_edges
     zu = rng.randn(U, shape.latent).astype(np.float32) / np.sqrt(shape.latent)
     zi = rng.randn(I, shape.latent).astype(np.float32) / np.sqrt(shape.latent)
     src = _zipf_choice(rng, U, E, shape.zipf_a).astype(np.int32)
-    # each user picks, among 8 Zipf candidates, the item of highest affinity
+    # each source picks, among 8 Zipf candidates, the destination of
+    # highest affinity: an item, or over one population another vertex (a
+    # candidate equal to the source is drawn again)
+    zd = zi if shape.bipartite else zu
     n_cand = 8
-    cand = _zipf_choice(rng, I, E * n_cand, shape.zipf_a).reshape(E, n_cand)
-    aff = np.einsum("el,ecl->ec", zu[src], zi[cand])
+    cand = _zipf_choice(rng, len(zd), E * n_cand, shape.zipf_a).reshape(
+        E, n_cand)
+    while not shape.bipartite and (loop := cand == src[:, None]).any():
+        cand[loop] = _zipf_choice(rng, U, int(loop.sum()), shape.zipf_a)
+    aff = np.einsum("el,ecl->ec", zu[src], zd[cand])
     aff += shape.noise * rng.randn(E, n_cand).astype(np.float32)
-    dst_item = cand[np.arange(E), np.argmax(aff, axis=1)].astype(np.int32)
+    dst = cand[np.arange(E), np.argmax(aff, axis=1)].astype(np.int32)
     gaps = (rng.pareto(shape.pareto_a, size=E) + 1.0) * shape.t_scale
     ts = np.cumsum(gaps).astype(np.float32)
     proj = rng.randn(2 * shape.latent, shape.f_edge).astype(np.float32)
     proj /= np.sqrt(2 * shape.latent)
-    lat = np.concatenate([zu[src], zi[dst_item]], axis=1)
+    lat = np.concatenate([zu[src], zd[dst]], axis=1)
     edge_feats = (lat @ proj + shape.noise * rng.randn(
         E, shape.f_edge).astype(np.float32)).astype(np.float32)
-    return Graph(src=src, dst=(dst_item + U).astype(np.int32), ts=ts,
-                 edge_feats=edge_feats, shape=shape)
+    if shape.bipartite:
+        dst = (dst + U).astype(np.int32)
+    return Graph(src=src, dst=dst, ts=ts, edge_feats=edge_feats, shape=shape,
+                 node_feats=_node_feats(shape, seed,
+                                        np.concatenate([zu, zi])))
+
+
+def _node_feats(shape: GraphShape, seed: int, latents: np.ndarray):
+    """``(n_nodes, f_feat)`` static features, a noisy projection of each
+    vertex's latent, drawn apart from the stream's own numbers; None where
+    the shape has none."""
+    if not shape.f_feat:
+        return None
+    rng = np.random.RandomState([seed % (2 ** 32), 0x6E6F6465])  # "node"
+    proj = rng.randn(shape.latent, shape.f_feat).astype(np.float32)
+    proj /= np.sqrt(shape.latent)
+    noise = rng.randn(latents.shape[0], shape.f_feat).astype(np.float32)
+    return (latents @ proj + shape.noise * noise).astype(np.float32)
 
 
 def vertex_gaps(graph: Graph) -> np.ndarray:

@@ -56,3 +56,57 @@ def test_schedule_offers_the_rate(burst):
     s2 = stream.schedule(traffic, reps2, 5.0, 2 ** 31 + 3)
     np.testing.assert_array_equal(s.due, s2.due)
     np.testing.assert_array_equal(s.eid, s2.eid)
+
+
+ACTORS = stream.GraphShape(n_users=16682, n_items=0, n_edges=20000,
+                           f_edge=0, f_feat=200, bipartite=False)
+
+
+def test_one_population_draws_both_roles_without_self_loops():
+    a = stream.generate(ACTORS, 2 ** 31 + 9)
+    b = stream.generate(ACTORS, 2 ** 31 + 9)
+    c = stream.generate(ACTORS, 7)
+    for x, y in ((a.src, b.src), (a.dst, b.dst), (a.ts, b.ts),
+                 (a.node_feats, b.node_feats)):
+        np.testing.assert_array_equal(x, y)
+    assert not np.array_equal(a.dst, c.dst)
+    assert not np.any(a.src == a.dst)
+    assert 0 <= min(a.src.min(), a.dst.min())
+    assert max(a.src.max(), a.dst.max()) < ACTORS.n_nodes == 16682
+    # one population: the busiest sources are busy destinations too
+    top = np.argsort(np.bincount(a.src, minlength=16682))[-20:]
+    assert np.all(np.bincount(a.dst, minlength=16682)[top] > 0)
+    assert a.edge_feats.shape == (20000, 0)
+    assert a.node_feats.shape == (16682, 200)
+    assert a.node_feats.dtype == np.float32
+    assert np.all(np.isfinite(a.node_feats)) and a.node_feats.std() > 0.1
+
+
+def test_one_population_batches_hold_a_vertex_in_both_roles():
+    # Zipf(1.2) over 16,682 actors: every 200-edge batch of this stream
+    # holds vertices that are a source and a destination in it (on average
+    # 27 a batch on two seeds), so the chronological last write across the
+    # two roles runs every round
+    g = stream.generate(ACTORS, 2 ** 31 + 9)
+    both = [np.intersect1d(g.src[i:i + 200], g.dst[i:i + 200]).size
+            for i in range(0, g.n_edges, 200)]
+    assert min(both) > 0
+    assert 15 < np.mean(both) < 40
+
+
+def test_node_features_do_not_move_the_stream():
+    # the stream's own draws are the same with and without node features
+    base = stream.GraphShape(n_users=60, n_items=40, n_edges=2000)
+    with_f = stream.GraphShape(n_users=60, n_items=40, n_edges=2000,
+                               f_feat=8)
+    a, b = stream.generate(base, 3), stream.generate(with_f, 3)
+    assert a.node_feats is None and b.node_feats.shape == (100, 8)
+    for x, y in ((a.src, b.src), (a.dst, b.dst), (a.ts, b.ts),
+                 (a.edge_feats, b.edge_feats)):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_one_population_has_no_items():
+    with pytest.raises(ValueError, match="n_items 0"):
+        stream.GraphShape(n_users=60, n_items=40, n_edges=10,
+                          bipartite=False)
