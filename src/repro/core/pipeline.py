@@ -276,7 +276,7 @@ class TGNPipeline:
         # --- 3. GNN embeddings (sampler + aggregator on updated memory) --
         nb = st.sampler(params, aux, state, edge_feats, vids, t_inst)
         s_self = state.memory[vids]
-        f_self = node_feats[vids] if node_feats is not None else None
+        f_self = stages.node_rows(node_feats, vids, self.cfg.f_feat)
         h, logits = st.aggregator(params, aux, nb, s_self, f_self)
 
         # --- 4. cache new messages (Most-Recent aggregator == LWW commit) -
@@ -306,7 +306,7 @@ class TGNPipeline:
         nb = self.stages.sampler(params, aux, state, edge_feats, vids,
                                  t_query)
         s_self = state.memory[vids]
-        f_self = node_feats[vids] if node_feats is not None else None
+        f_self = stages.node_rows(node_feats, vids, self.cfg.f_feat)
         h, logits = self.stages.aggregator(params, aux, nb, s_self, f_self)
         return h, logits, nb.full_valid, nb.full_dt
 

@@ -68,11 +68,11 @@ def kernel_tier(use_kernels) -> str:
 
 def fused_supported(cfg) -> bool:
     """The fused single-pass kernel covers the co-designed student tail:
-    SAT attention + LUT encoder (any prune budget / sampler backend),
-    without static node features (the paper's Wikipedia/Reddit setting —
-    f_feat > 0 would add a feature projection the kernel does not carry)."""
-    return (cfg.attention == "sat" and cfg.encoder == "lut"
-            and cfg.f_feat == 0)
+    SAT attention + LUT encoder (any prune budget / sampler backend), with
+    or without static node features (Eq. 11's ``W_s f + b_s`` runs inside
+    the kernel on the vertex's own row; GDELT's setting) and with or
+    without edge features."""
+    return cfg.attention == "sat" and cfg.encoder == "lut"
 
 
 def resolved_tier(cfg, use_kernels) -> str:
@@ -93,6 +93,18 @@ def edge_rows(edge_feats: jax.Array, eids: jax.Array,
     once at construction and every tier reads."""
     x = edge_feats[eids]
     return x if edge_feats.ndim == 2 else x[..., 0, :f_edge]
+
+
+def node_rows(node_feats: jax.Array | None, vids: jax.Array,
+              f_feat: int) -> jax.Array | None:
+    """Rows ``vids`` of the static node-feature table, native
+    ``(..., f_feat)``, or None where the model has none: like
+    ``edge_rows``, the table is native ``(V, f_feat)`` or in the row
+    layout a serving session lays it out in once."""
+    if node_feats is None:
+        return None
+    x = node_feats[vids]
+    return x if node_feats.ndim == 2 else x[..., 0, :f_feat]
 
 
 def row_state(state):
@@ -513,6 +525,11 @@ class LastWriteWinsCommitter:
     chronologically-last valid update of each vertex survives. The winner
     mask is computed ONCE per batch and shared by the memory commit and the
     mail commit (both race over the same (vids, vvalid) layout).
+
+    Both commits run under ``jax.named_scope("lww_commit")``: their
+    device operations carry the label in the compiled program's op names,
+    which is how a profiler trace finds them (metadata only; no numerics
+    change).
     """
 
     def winners(self, vids: jax.Array, vvalid: jax.Array,
@@ -520,6 +537,7 @@ class LastWriteWinsCommitter:
         return updater.last_write_wins(vids, vvalid,
                                        updater.interleave_order(B))
 
+    @jax.named_scope("lww_commit")
     def commit_memory(self, state, vids, winners, s_upd, lu_upd):
         """Commit updated memory rows; consuming mail invalidates it."""
         mem_t = updater.commit(state.memory, vids, s_upd, winners)
@@ -531,6 +549,7 @@ class LastWriteWinsCommitter:
         return state._replace(memory=mem_t, last_update=lu_t,
                               mail_valid=mv_t)
 
+    @jax.named_scope("lww_commit")
     def commit_mail(self, state, vids, winners, new_mail, t_inst):
         """Cache new messages (Most-Recent aggregator == LWW commit)."""
         mail_t = updater.commit(state.mail, vids, new_mail, winners)
@@ -559,10 +578,11 @@ def make_fused_step(cfg):
     the state commits — genuine state writes the paper's design also pays —
     stay in XLA after the launch.
 
-    The kernel reads memory, mailbox and edge features in the row layout
-    (``row_state``, ``kernels/ops.row_table``), so the state and the edge
-    table must arrive in it — ``TGNPipeline.resident`` and a serving
-    session's edge table: laid out once, never per step.
+    The kernel reads memory, mailbox, edge features and (with ``f_feat >
+    0``) node features in the row layout (``row_state``,
+    ``kernels/ops.row_table``), so the state and the feature tables must
+    arrive in it — ``TGNPipeline.resident`` and a serving session's
+    feature tables: laid out once, never per step.
     """
     from repro.kernels import ops as kops  # local: keep core importable
     from repro.core import tgn             # local: BatchOut (no cycle)
@@ -571,11 +591,13 @@ def make_fused_step(cfg):
     committer = LastWriteWinsCommitter()
     V = cfg.n_nodes
 
-    def datapath(params, aux, state, edge_feats, vids, t_inst, winners):
+    def datapath(params, aux, state, edge_feats, node_feats, vids, t_inst,
+                 winners):
         """Metadata + the one launch. This function must never materialize
-        a neighbor row itself: only ids/timestamps/validity leave XLA
-        (tools/session_lint.py AST-guards it against jnp.concatenate and
-        memory/mail/edge-feature gathers creeping back in)."""
+        a neighbor or feature row itself: only ids/timestamps/validity
+        leave XLA (tools/session_lint.py AST-guards it against
+        jnp.concatenate and memory/mail/edge/node-feature gathers creeping
+        back in)."""
         sel = select(params, aux, state, vids, t_inst)
         mail_ts = state.mail_ts[vids]
         lu_prev = state.last_update[vids]
@@ -592,7 +614,7 @@ def make_fused_step(cfg):
         h, s_upd = kops.fused_step(
             vids, sel.ids, sel.eids, hit, mail_ts - lu_prev, mail_ok,
             sel.dt, sel.logits, sel.valid, state.memory, state.mail,
-            edge_feats, aux["packed_fused"])
+            edge_feats, node_feats, aux["packed_fused"])
         lu_upd = jnp.where(mail_ok, mail_ts, lu_prev)
         return sel, h, s_upd, lu_upd
 
@@ -600,13 +622,16 @@ def make_fused_step(cfg):
               node_feats):
         src, dst, eid, ts, valid = batch
         B = src.shape[0]
-        if state.memory.ndim != 3 or edge_feats.ndim != 3:
+        if (state.memory.ndim != 3 or edge_feats.ndim != 3
+                or (cfg.f_feat and (node_feats is None
+                                    or node_feats.ndim != 3))):
             raise ValueError("the fused tier steps row-layout tables: pass "
                              "pipeline.resident(state) and "
-                             "kernels.ops.row_table(edge_feats)")
+                             "kernels.ops.row_table of the edge (and node) "
+                             "features")
         winners = committer.winners(vids, vvalid, B)
         sel, h, s_upd, lu_upd = datapath(params, aux, state, edge_feats,
-                                         vids, t_inst, winners)
+                                         node_feats, vids, t_inst, winners)
         state = committer.commit_memory(state, vids, winners,
                                         kops.row_table(s_upd), lu_upd)
         # mail build: committed memory of a VALID row r is exactly

@@ -28,7 +28,9 @@ This kernel is the whole post-prune datapath in ONE ``pallas_call``:
     read back from the phase-0 scratch, not from stale HBM — the
     chronological-commit view the staged path gets from its scatter),
     split-matmul kv projection (no concat), folded-LUT time rows, masked
-    softmax, FAM reduction and the output transform.
+    softmax, FAM reduction and the output transform. With static node
+    features (GDELT) the tile's own rows first take Eq. 11's projection,
+    ``f' = s + W_s f + b_s``, their feature rows DMA'd like the rest.
 
 The TPU grid is sequential, so ``grid=(2, T)`` runs every phase-0 tile
 before any phase-1 tile — exactly the MUU->commit->EU ordering of
@@ -37,7 +39,8 @@ Algorithm 1 — and the scratch carries the updated rows across grid steps.
 VMEM working set per tile (fp32 words): the persistent updated-row buffer
 ``R_p x m_p`` plus gather buffers ``block_b x f_p`` (mail) and
 ``block_b*k x (m_p + e_p)`` (neighbors) plus the weights
-(``f_p x 3m_p + m_p x 3m_p + m_p x d_p + e_p x d_p + 2 E x (3m_p|d_p)``).
+(``f_p x 3m_p + m_p x 3m_p + m_p x d_p + e_p x d_p + 2 E x (3m_p|d_p)``),
+and with node features ``block_b x n_p + n_p x m_p`` more.
 For paper dims (B=256 -> R=512, k=4, f_mem=100, f_edge=172, E=128) that is
 ~2.1 MiB — comfortably inside one core's 16 MiB.
 
@@ -71,13 +74,17 @@ def _fused_kernel(  # scalar prefetch (SMEM)
                   w_i_ref, w_h_ref, b_i_ref, b_h_ref, gb_ref, gt_ref,
                   wv_mem_ref, wv_edge_ref, b_v_ref, sb_ref, st_ref,
                   w_self_ref, w_agg_ref, b_out_ref,
-                  # outputs
-                  h_ref, supd_ref,
-                  # scratch
-                  supd_all, mail_scr, self_scr, nbr_s, nbr_e, sem,
-                  *, k: int, f_edge: int, n_entries: int, block_b: int):
+                  *rest, k: int, f_edge: int, f_feat: int, n_entries: int,
+                  block_b: int):
     """One grid step of the two-phase single-pass datapath (see module
-    docstring for the shapes)."""
+    docstring for the shapes). ``rest`` is, with node features
+    (``f_feat > 0``), the node-feature row table and ``W_s``/``b_s``;
+    then the outputs ``h``/``s_upd``; then the scratch buffers, the
+    node-feature tile last."""
+    if f_feat:
+        nf_hbm, w_s_ref, b_s_ref, *rest = rest
+    (h_ref, supd_ref, supd_all, mail_scr, self_scr, nbr_s, nbr_e, sem,
+     *feat_scr) = rest
     ph = pl.program_id(0)
     t = pl.program_id(1)
     bb = block_b
@@ -165,25 +172,40 @@ def _fused_kernel(  # scalar prefetch (SMEM)
 
         # --- FAM reduction + output transform (split, no concat) ---------
         agg = jnp.sum(attn[:, :, None] * v, axis=1)
-        fp = supd_all[pl.ds(t * bb, bb)].reshape(bb, m_p)
+        s_self = supd_all[pl.ds(t * bb, bb)].reshape(bb, m_p)
+        fp = s_self
+        if f_feat:
+            # Eq. 11: f' = s + W_s f + b_s on the vertex's own row only
+            # (neighbours enter the kv projection with memory alone)
+            (feat,) = feat_scr
+
+            def fetch_feat(i, _):
+                copy(nf_hbm.at[vids_ref[t * bb + i]], feat.at[i])
+                return 0
+
+            jax.lax.fori_loop(0, bb, fetch_feat, 0)
+            fp = fp + jnp.dot(feat[...].reshape(bb, feat.shape[2]),
+                              w_s_ref[...],
+                              preferred_element_type=jnp.float32)
+            fp = fp + b_s_ref[...]
         h = jnp.dot(fp, w_self_ref[...],
                     preferred_element_type=jnp.float32)
         h = h + jnp.dot(agg, w_agg_ref[...],
                         preferred_element_type=jnp.float32)
         h_ref[...] = h + b_out_ref[...]
-        supd_ref[...] = fp
+        supd_ref[...] = s_self
 
 
-@functools.partial(jax.jit, static_argnames=("k", "f_edge", "block_b",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "f_edge", "f_feat",
+                                             "block_b", "interpret"))
 def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
                       sel_dt, sel_logits, sel_valid,
                       memory, mail, edge_feats,
                       w_i, w_h, b_i, b_h, g_bounds, g_table,
                       wv_mem, wv_edge, b_v, s_bounds, s_table,
-                      w_self, w_agg, b_out,
-                      *, k: int, f_edge: int, block_b: int,
-                      interpret: bool = False):
+                      w_self, w_agg, b_out, node_feats=None, w_s=None,
+                      b_s=None, *, k: int, f_edge: int, f_feat: int = 0,
+                      block_b: int, interpret: bool = False):
     """One launch for the post-prune datapath of one batch.
 
     Scalar prefetch (int32): ``vids`` (R,), flat ``sel_ids``/``sel_eid``/
@@ -194,7 +216,10 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
     layout, zero lane padding): ``memory`` (V, 1, m_p), ``mail``
     (V, 1, f_p), ``edge_feats`` (E_rows, 1, e_p). Weights are
     kernel-layout (lane-padded OUT dims, gate blocks at m_p strides; see
-    ops.pad_fused_params).
+    ops.pad_fused_params). With static node features (``f_feat > 0``,
+    Eq. 11) also the row table ``node_feats`` (V, 1, n_p) and ``w_s``
+    (n_p, m_p), ``b_s`` (1, m_p); with ``f_feat == 0`` they are not
+    operands, and nothing of Eq. 11 is traced.
     R must be a multiple of ``block_b``. Returns ``(h, s_upd)`` —
     (R, emb_p) embeddings and (R, m_p) updated memory rows.
     """
@@ -212,6 +237,18 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
     assert memory.shape[1:] == (1, m_p), (memory.shape, m_p)
     assert mail.shape[1:] == (1, f_p), (mail.shape, f_p)
     assert edge_feats.shape[1:] == (1, e_p), (edge_feats.shape, e_p)
+    feat_in, feat_specs, feat_scr = (), [], []
+    if f_feat:
+        n_p = w_s.shape[0]
+        assert node_feats.shape[1:] == (1, n_p), (node_feats.shape, n_p)
+        assert w_s.shape == (n_p, m_p) and b_s.shape == (1, m_p)
+        feat_in = (node_feats, w_s, b_s)
+        feat_specs = [
+            pl.BlockSpec(memory_space=pl.ANY),          # node features
+            pl.BlockSpec((n_p, m_p), lambda ph, t, *_: (0, 0)),
+            pl.BlockSpec((1, m_p), lambda ph, t, *_: (0, 0)),
+        ]
+        feat_scr = [pltpu.VMEM((block_b, 1, n_p), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -239,6 +276,7 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
             pl.BlockSpec((m_p, emb_p), lambda ph, t, *_: (0, 0)),
             pl.BlockSpec((d_p, emb_p), lambda ph, t, *_: (0, 0)),
             pl.BlockSpec((1, emb_p), lambda ph, t, *_: (0, 0)),
+            *feat_specs,
         ],
         out_specs=[
             pl.BlockSpec((block_b, emb_p), lambda ph, t, *_: (t, 0)),
@@ -251,11 +289,12 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
             pltpu.VMEM((nk, 1, m_p), jnp.float32),      # winner memory rows
             pltpu.VMEM((nk, 1, e_p), jnp.float32),      # winner edge rows
             pltpu.SemaphoreType.DMA,
+            *feat_scr,                                  # node-feature tile
         ],
     )
     return pl.pallas_call(
-        functools.partial(_fused_kernel, k=k, f_edge=f_edge, n_entries=E,
-                          block_b=block_b),
+        functools.partial(_fused_kernel, k=k, f_edge=f_edge, f_feat=f_feat,
+                          n_entries=E, block_b=block_b),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((R, emb_p), jnp.float32),
                    jax.ShapeDtypeStruct((R, m_p), jnp.float32)],
@@ -263,4 +302,4 @@ def fused_step_pallas(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok,
     )(vids, sel_ids, sel_eid, hit, dt_mail, mail_ok, sel_dt, sel_logits,
       sel_valid, memory, mail, edge_feats, w_i, w_h, b_i, b_h, g_bounds,
       g_table, wv_mem, wv_edge, b_v, s_bounds, s_table, w_self, w_agg,
-      b_out)
+      b_out, *feat_in)

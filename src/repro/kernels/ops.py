@@ -262,7 +262,9 @@ def pad_fused_params(gru_params: dict, attn_params: dict, folded_gru: dict,
       * W_v split at the memory/edge boundary — the kernel computes the kv
         projection as TWO matmuls, so the ``(B, k, Dkv)`` concat never
         exists — plus the attention-folded LUT table (E, d_p);
-      * the output transform split the same way (self rows || aggregate).
+      * the output transform split the same way (self rows || aggregate);
+      * with static node features, Eq. 11's ``W_s`` (f_feat_p, m_p) and
+        ``b_s`` (1, m_p), from ``attn_params["feat"]``.
     """
     m_p = round_up(f_mem)
     e_p = round_up(max(f_edge, 1))
@@ -280,7 +282,9 @@ def pad_fused_params(gru_params: dict, attn_params: dict, folded_gru: dict,
     wv_edge = (w_v[f_mem:f_mem + f_edge] if f_edge
                else jnp.zeros((1, d), jnp.float32))
     w_out = attn_params["w_out"]
-    return {
+    feat = attn_params.get("feat", {})
+    f_feat = feat["w_s"].shape[0] if "w_s" in feat else 0
+    packed = {
         "w_i": gru["w_i"], "w_h": gru["w_h"],
         "b_i": gru["b_i"], "b_h": gru["b_h"],
         "g_bounds": _sentinel_bounds(folded_gru["boundaries"], E),
@@ -297,29 +301,38 @@ def pad_fused_params(gru_params: dict, attn_params: dict, folded_gru: dict,
         "w_agg": _pad2(w_out[f_mem:].astype(jnp.float32), d_p, emb_p),
         "b_out": jnp.pad(attn_params["b_out"].astype(jnp.float32),
                          (0, emb_p - f_emb))[None, :],
-        "f_mem": f_mem, "f_edge": f_edge, "f_mail": f_mail_raw,
-        "f_emb": f_emb,
+        "f_mem": f_mem, "f_edge": f_edge, "f_feat": f_feat,
+        "f_mail": f_mail_raw, "f_emb": f_emb,
     }
+    if f_feat:
+        packed["w_s"] = _pad2(feat["w_s"].astype(jnp.float32),
+                              round_up(f_feat), m_p)
+        packed["b_s"] = jnp.pad(feat["b_s"].astype(jnp.float32),
+                                (0, m_p - f_mem))[None, :]
+    return packed
 
 
 def fused_step(vids: jax.Array, sel_ids: jax.Array, sel_eid: jax.Array,
                hit: jax.Array, dt_mail: jax.Array, mail_ok: jax.Array,
                sel_dt: jax.Array, sel_logits: jax.Array,
                sel_valid: jax.Array, memory: jax.Array, mail: jax.Array,
-               edge_feats: jax.Array, packed: dict,
-               *, block_b: int = 128):
+               edge_feats: jax.Array, node_feats: jax.Array | None,
+               packed: dict, *, block_b: int = 128):
     """ONE launch for the post-prune datapath: winner-row gather + kv
-    projection + folded-LUT rows + masked softmax + FAM + output transform
-    + GRU memory update.
+    projection + folded-LUT rows + masked softmax + FAM + Eq. 11's
+    node-feature projection (where the model has node features) + output
+    transform + GRU memory update.
 
     ``vids`` (R,) int; ``sel_ids``/``sel_eid``/``hit`` (R, k) int —
     ``hit[r, j] >= 0`` marks a winner whose vertex is updated by THIS
     batch and names the batch row holding its updated memory (the
     committed view); ``dt_mail``/``mail_ok`` (R,); ``sel_dt``/
     ``sel_logits``/``sel_valid`` (R, k). ``memory``/``mail``/
-    ``edge_feats`` are the HBM-resident tables in the ``row_table``
-    layout — the kernel fetches only the addressed rows, and nothing here
-    copies a table. Returns ``(h (R, f_emb), s_upd (R, f_mem))``.
+    ``edge_feats`` (and ``node_feats`` where ``packed`` carries ``W_s``;
+    it is not read otherwise) are the HBM-resident tables in the
+    ``row_table`` layout — the kernel fetches only the addressed rows, and
+    nothing here copies a table. Returns ``(h (R, f_emb), s_upd (R,
+    f_mem))``.
     """
     _count_launch()
     R, k = sel_ids.shape
@@ -334,6 +347,8 @@ def fused_step(vids: jax.Array, sel_ids: jax.Array, sel_eid: jax.Array,
     def f32(x, padder=p1):
         return jnp.pad(x.astype(jnp.float32), padder)
 
+    f_feat = packed["f_feat"]
+    feat = (node_feats, packed["w_s"], packed["b_s"]) if f_feat else ()
     h, s_upd = fused_step_pallas(
         i32(vids), i32(sel_ids, p2).reshape(-1),
         i32(sel_eid, p2).reshape(-1),
@@ -345,7 +360,7 @@ def fused_step(vids: jax.Array, sel_ids: jax.Array, sel_eid: jax.Array,
         packed["g_bounds"], packed["g_table"], packed["wv_mem"],
         packed["wv_edge"], packed["b_v"], packed["s_bounds"],
         packed["s_table"], packed["w_self"], packed["w_agg"],
-        packed["b_out"],
-        k=k, f_edge=packed["f_edge"], block_b=bb,
+        packed["b_out"], *feat,
+        k=k, f_edge=packed["f_edge"], f_feat=f_feat, block_b=bb,
         interpret=_use_interpret())
     return h[:R, :packed["f_emb"]], s_upd[:R, :packed["f_mem"]]

@@ -166,6 +166,21 @@ class _HostStager:
         self._last = turn
         return dev
 
+    def superseded(self) -> int:
+        """Valid rows of the last staged set that the round's commit
+        supersedes: per tenant row, ``concat([src, dst])`` is the batch's
+        vertex instances, and every valid instance of a vertex but its
+        chronologically last loses the last-write-wins commit (a vertex
+        seen twice, in one role or in both). One row-wise sort of the
+        set's vertex ids on the host, invalid instances set to -1; the
+        device is not read."""
+        src, dst, _eid, _ts, valid = self._bufs[self._last]
+        ids = np.where(np.concatenate([valid, valid], axis=1),
+                       np.concatenate([src, dst], axis=1), -1)
+        ids.sort(axis=1)
+        return int(np.count_nonzero((ids[:, 1:] == ids[:, :-1])
+                                    & (ids[:, 1:] >= 0)))
+
     def note_consumer(self, outputs) -> None:
         """Join ``outputs`` (any pytree of device arrays produced by the
         launch that consumed the last staged set) into that set's reuse
@@ -564,11 +579,17 @@ class SessionManager:
         #: DEFAULT_PARAMS entry, more arrive via ``register_params``
         self.param_store = ParamStore(params, place=self._place_params)
         self.params = self.param_store.get(DEFAULT_PARAMS)
-        #: the shared edge-feature table, laid out ONCE in the kernels' row
-        #: layout (``kernels/ops.row_table``): every tier reads it, and the
-        #: fused kernel DMAs single rows from it without a per-step copy
-        self.edge_feats = kops.row_table(jnp.asarray(edge_feats))
-        self.node_feats = (jnp.asarray(node_feats)
+        #: the shared edge- and node-feature tables, laid out ONCE in the
+        #: kernels' row layout (``kernels/ops.row_table``): every tier
+        #: reads them, and the fused kernel DMAs single rows from them
+        #: without a per-step copy. A model without edge features keeps
+        #: one zero placeholder row, not a padded row per edge that
+        #: nothing reads.
+        edge_feats = jnp.asarray(edge_feats)
+        if edge_feats.shape[-1] == 0:
+            edge_feats = jnp.zeros((1, 0), jnp.float32)
+        self.edge_feats = kops.row_table(edge_feats)
+        self.node_feats = (kops.row_table(jnp.asarray(node_feats))
                            if node_feats is not None else None)
         # keyed by (cfg, resolved kernel tier, param-set name): tenants
         # may pick a kernel tier (add_tenant(use_kernels=...)) and a
@@ -992,6 +1013,8 @@ class SessionManager:
         with span("session.stage", trace, rows=len(rows),
                   width=width) as staged:
             superbatch = self._stager.stage(rows)
+            self.obs.counter("session.superseded_rows").inc(
+                self._stager.superseded())
         with span("session.dispatch", trace, lanes=len(cohorts)):
             states = tuple(c.state for c in cohorts)
             # per-segment padded widths (static): each cohort steps at ITS
@@ -1102,7 +1125,9 @@ class SessionManager:
         compiled split per submitting cohort (``_split_out``), plus one
         compiled trim (``_trim_out``) for each tenant that submitted
         fewer rows than its cohort's round width; the registry counts
-        both (``session.output_splits``, ``session.output_trims``).
+        both (``session.output_splits``, ``session.output_trims``), and
+        the valid rows each coalesced round's last-write-wins commit
+        supersedes (``session.superseded_rows``, ``_HostStager.superseded``).
 
         Steps are fully asynchronous: nothing here blocks on the device,
         so staging round k+1 overlaps the compute of round k. ``sync()``

@@ -134,25 +134,59 @@ def test_neg_inf_is_single_sourced():
 FUSED_VARIANTS = ("sat+lut", "sat+lut+np6", "sat+lut+np4", "sat+lut+np2",
                   "sat+lut+np4+uniform", "sat+lut+np4+reservoir")
 
+#: (variant, graph, oracle tier) of each fused trajectory check: every
+#: student variant on a Wikipedia-shaped stream (two populations, 172-d
+#: edge features) against the staged kernels, and np4 on a GDELT-shaped
+#: one (paper Table II: 200-d node features, no edge features; one
+#: population, so a vertex can be a source and a destination in one
+#: batch) against the float32 reference tier.
+FUSED_CASES = ([(v, "wikipedia", "staged") for v in FUSED_VARIANTS]
+               + [("sat+lut+np4", "gdelt", "ref")])
 
-def _fused_setup(variant, key=0, f=16, n_edges=300):
+
+def _one_population(n_nodes=100, n_edges=300, f_feat=200, seed=2):
+    """A GDELT-shaped stream: both endpoints Zipf over one population
+    (self-loops drawn again), 200-d static node features, no edge
+    features."""
+    from repro.data import temporal_graph as tgd
+    rng = np.random.RandomState(seed)
+    p = np.arange(1, n_nodes + 1, dtype=np.float64) ** -1.2
+    p /= p.sum()
+    src = rng.choice(n_nodes, n_edges, p=p).astype(np.int32)
+    dst = rng.choice(n_nodes, n_edges, p=p).astype(np.int32)
+    while (loop := src == dst).any():
+        dst[loop] = rng.choice(n_nodes, int(loop.sum()), p=p)
+    ts = np.cumsum(rng.pareto(1.1, n_edges) + 1.0).astype(np.float32) * 60
+    return tgd.TemporalGraph(
+        src=src, dst=dst, ts=ts, edge_feats=np.zeros((n_edges, 0),
+                                                     np.float32),
+        node_feats=rng.randn(n_nodes, f_feat).astype(np.float32),
+        cfg=tgd.StreamConfig(n_users=n_nodes, n_items=0, n_edges=n_edges,
+                             f_edge=0, f_feat=f_feat))
+
+
+def _fused_setup(variant, key=0, f=16, n_edges=300, graph="wikipedia",
+                 oracle="staged"):
+    """``(graph, oracle pipeline, fused pipeline, params)``."""
     from repro.core import pipeline as pl
     from repro.data import temporal_graph as tgd
-    g = tgd.wikipedia_like(n_edges=n_edges)
-    dims = dict(n_nodes=g.cfg.n_nodes, n_edges=g.n_edges, f_edge=172,
-                f_mem=f, f_time=f, f_emb=f, m_r=10)
+    g = (tgd.wikipedia_like(n_edges=n_edges) if graph == "wikipedia"
+         else _one_population(n_edges=n_edges))
+    dims = dict(n_nodes=g.cfg.n_nodes, n_edges=g.n_edges,
+                f_edge=g.cfg.f_edge, f_feat=g.cfg.f_feat, f_mem=f, f_time=f,
+                f_emb=f, m_r=10)
     cfg = pl.variant_config(variant, **dims)
-    staged = pl.build_pipeline(cfg, use_kernels=True)
+    ref = pl.build_pipeline(cfg, use_kernels=oracle)
     fused = pl.build_pipeline(cfg, use_kernels="fused")
-    params = staged.init_params(jax.random.key(key))
-    return g, staged, fused, params
+    params = ref.init_params(jax.random.key(key))
+    return g, ref, fused, params
 
 
 def _batches(g, n, B, ragged=False):
     from repro.data import stream as stream_mod
     out = []
     for i, b in enumerate(stream_mod.fixed_count(
-            g, B, window=slice(0, n * B))):
+            g, B, window=slice(0, n * B), item_range=(0, g.cfg.n_nodes))):
         valid = np.asarray(b.valid).copy()
         if ragged and i == 1:
             valid[B // 2:] = False        # ragged round: half padding
@@ -161,20 +195,33 @@ def _batches(g, n, B, ragged=False):
     return out
 
 
-@pytest.mark.parametrize("variant", FUSED_VARIANTS)
-def test_fused_step_matches_staged_trajectory(variant):
-    """The one-launch fused step reproduces the staged-kernel trajectory
+@pytest.mark.parametrize("variant,graph,oracle", FUSED_CASES)
+def test_fused_step_matches_staged_trajectory(variant, graph, oracle):
+    """The one-launch fused step reproduces the oracle tier's trajectory
     (state AND embeddings AND distill views) within the staged kernels'
     own tolerances, for every prune budget / sampler backend — including
-    a ragged round whose padding rows must commit nothing."""
-    g, staged, fused, params = _fused_setup(variant)
+    a ragged round whose padding rows must commit nothing — and, on a
+    GDELT-shaped stream, Eq. 11's node-feature projection and the
+    last-write-wins commit across a vertex's two roles in one batch,
+    against the reference tier (``tgn.process_batch``'s composition) at
+    ``highest`` precision."""
+    g, staged, fused, params = _fused_setup(variant, graph=graph,
+                                            oracle=oracle)
     ef = jnp.asarray(g.edge_feats)
     ef_rows = ops.row_table(ef)
+    nf = None if g.node_feats is None else jnp.asarray(g.node_feats)
+    nf_rows = None if nf is None else ops.row_table(nf)
     ss, sf = staged.init_state(), fused.resident(fused.init_state())
     assert fused.tier == "fused" and fused.stages.fused is not None
+    assert staged.tier == oracle
+    cross_role = 0
     for b in _batches(g, 4, 30, ragged=True):
-        os_ = staged.step_fn(params, ss, b, ef)
-        of_ = fused.step_fn(params, sf, b, ef_rows)
+        ok = np.asarray(b[4])
+        cross_role += np.intersect1d(np.asarray(b[0])[ok],
+                                     np.asarray(b[1])[ok]).size
+        with jax.default_matmul_precision("highest"):
+            os_ = staged.step_fn(params, ss, b, ef, nf)
+            of_ = fused.step_fn(params, sf, b, ef_rows, nf_rows)
         ss, sf = os_.state, of_.state
         sf_native = fused.native(sf)
         m = np.asarray(b[4])[:, None]
@@ -193,6 +240,33 @@ def test_fused_step_matches_staged_trajectory(variant):
                 np.asarray(getattr(ss, field)),
                 np.asarray(getattr(sf_native, field)), atol=2e-5,
                 err_msg=f"{variant}:{field}")
+    # one population: the commit across roles ran (two populations never
+    # share a vertex between the roles)
+    assert (cross_role > 0) == (graph == "gdelt"), cross_role
+
+
+def test_fused_node_feature_projection_is_load_bearing():
+    """Zeroing Eq. 11's ``W_s`` moves the fused tier's embeddings far off
+    (the projection is computed, not dropped) and leaves its memory
+    bitwise as it was (f' feeds the output transform alone)."""
+    g, _ref, fused, params = _fused_setup("sat+lut+np4", graph="gdelt",
+                                          oracle="ref")
+    ef = ops.row_table(jnp.asarray(g.edge_feats))
+    nf = ops.row_table(jnp.asarray(g.node_feats))
+    feat = params["attn"]["feat"]
+    zeroed = dict(params, attn=dict(params["attn"], feat=dict(
+        feat, w_s=jnp.zeros_like(feat["w_s"]))))
+    st = fused.resident(fused.init_state())
+    for b in _batches(g, 2, 30):
+        out = fused.step_fn(params, st, b, ef, nf)
+        off = fused.step_fn(zeroed, st, b, ef, nf)
+        gap = float(jnp.max(jnp.abs(out.emb_src - off.emb_src)))
+        assert gap > 1e-2, gap
+        for f in st._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(out.state, f)),
+                                          np.asarray(getattr(off.state, f)),
+                                          err_msg=f)
+        st = out.state
 
 
 def test_fused_step_all_invalid_batch_is_bitwise_noop():

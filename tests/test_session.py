@@ -557,6 +557,77 @@ def test_coalesced_outputs_come_from_one_split_per_cohort(small_graph,
     assert max(compiles[:2]) <= 1 and compiles[2:] == [0] * 4, compiles
 
 
+def test_superseded_rows_count_every_lost_commit(small_graph):
+    """``session.superseded_rows`` counts, per coalesced round, the valid
+    vertex instances of each tenant's batch that the last-write-wins
+    commit supersedes: a vertex met again, in the same role or in the
+    other one; padding rows count for nothing."""
+    g = small_graph
+    cfg = pl.variant_config("sat+lut+np4", **_dims(g, f=8))
+    mgr = SessionManager(tgn.init_params(jax.random.key(3), cfg),
+                         jnp.asarray(g.edge_feats), model=cfg)
+    a, b = mgr.add_tenant(), mgr.add_tenant()
+
+    def batch(src, dst, valid, t0):
+        n = len(src)
+        return (np.asarray(src, np.int32), np.asarray(dst, np.int32),
+                np.arange(n, dtype=np.int32),
+                t0 + np.arange(n, dtype=np.float32), np.asarray(valid, bool))
+
+    rounds = (
+        # a: vertex 1 is a source, then a destination (cross-role);
+        # b: 7 and 9 twice each, its third edge padding
+        ({a: batch([1, 2, 3], [604, 1, 605], [1, 1, 1], 10.0),
+          b: batch([7, 7, 8], [609, 609, 610], [1, 1, 0], 10.0)}, 3),
+        # no vertex met twice; b idle
+        ({a: batch([4, 5], [606, 607], [1, 1], 20.0)}, 0),
+        # a valid row and a padding row of the same vertex: nothing lost
+        ({b: batch([11, 11], [612, 613], [1, 0], 30.0)}, 0),
+    )
+    for batches, want in rounds:
+        before = mgr.obs.snapshot().get("session.superseded_rows", 0)
+        mgr.step(batches)
+        got = mgr.obs.snapshot()["session.superseded_rows"] - before
+        # the rule spelled out: valid instances less distinct vertices
+        ids = [np.concatenate([s_[v], d_[v]]) for s_, d_, _e, _t, v in
+               (tuple(np.asarray(x) for x in bt) for bt in batches.values())]
+        assert got == want == sum(len(i) - len(set(i)) for i in ids)
+
+
+@pytest.mark.parametrize("tier", stages.KERNEL_TIERS)
+def test_zero_width_edge_table_is_one_placeholder_row(tier):
+    """A model without edge features (GDELT) keeps ONE placeholder row of
+    the edge table, not a 512 B padded row per edge that nothing reads;
+    every tier's trajectory is bitwise the one it follows on the padded
+    table."""
+    from repro.kernels import ops as kops
+    g = tgd.gdelt_like(n_edges=200)
+    dims = dict(n_nodes=g.cfg.n_nodes, n_edges=g.n_edges, f_edge=0,
+                f_feat=g.cfg.f_feat, f_mem=16, f_time=16, f_emb=16, m_r=10)
+    cfg = pl.variant_config("sat+lut+np4", **dims)
+    params = tgn.init_params(jax.random.key(5), cfg)
+    mgr = SessionManager(params, g.edge_feats, g.node_feats, model=cfg,
+                         use_kernels=tier)
+    assert mgr.edge_feats.shape == (1, 1, 128)
+    padded = kops.row_table(jnp.asarray(g.edge_feats))
+    assert padded.shape == (g.n_edges, 1, 128)
+    pipe = pl.build_pipeline(cfg, use_kernels=tier)
+    assert pipe.tier == tier
+    st_one = st_pad = pipe.resident(pipe.init_state())
+    for bt in stream_mod.fixed_count(g, 40, window=slice(0, 120)):
+        bt = tuple(jnp.asarray(x) for x in
+                   (bt.src, bt.dst, bt.eid, bt.ts, bt.valid))
+        one = pipe.step_fn(params, st_one, bt, mgr.edge_feats,
+                           mgr.node_feats)
+        pad = pipe.step_fn(params, st_pad, bt, padded, mgr.node_feats)
+        for f in tgn.BatchOut._fields[1:]:
+            np.testing.assert_array_equal(np.asarray(getattr(one, f)),
+                                          np.asarray(getattr(pad, f)),
+                                          err_msg=f)
+        _assert_state_equal(one.state, pad.state, tier)
+        st_one, st_pad = one.state, pad.state
+
+
 # ---------------------------------------------------------------------------
 # per-lane parameter sets: teacher/student A/B serving in one launch
 # ---------------------------------------------------------------------------

@@ -113,3 +113,20 @@ def test_output_rule_guards_the_round(lint, tmp_path, monkeypatch):
     checked, errors = lint.check_outputs(
         "s.py", (("SessionManager", "_coalesced_round"),))
     assert checked == 0 and "not found" in errors[0]
+
+
+@pytest.mark.parametrize("body, found", [
+    # metadata reads are the datapath's business
+    ("ts = state.mail_ts[vids]", []),
+    ("hit = win_rows[sel.ids]", []),
+    # row gathers belong to the kernel's DMA: neighbour memory, mail,
+    # edge-feature rows, and the tile's own node-feature rows (Eq. 11)
+    ("s = state.memory[sel.ids]", ["subscript gather of 'memory'"]),
+    ("m = state.mail[vids]", ["subscript gather of 'mail'"]),
+    ("e = edge_feats[sel.eids]", ["subscript gather of 'edge_feats'"]),
+    ("f = node_feats[vids]", ["subscript gather of 'node_feats'"]),
+    ("kv = jnp.concatenate([a, b])", ["jnp.concatenate"]),
+])
+def test_fused_datapath_rule(lint, body, found):
+    (fn,) = ast.parse(f"def datapath():\n    {body}\n").body
+    assert [w for _l, w in lint._violations(fn, lint.FUSING, True)] == found

@@ -73,8 +73,8 @@ def _compile(fn, *args, sharding):
     return text
 
 
-def _student(spec="sat+lut+np4", tier="fused"):
-    pipe = pl.build_pipeline(spec, use_kernels=tier, **DIMS)
+def _student(spec="sat+lut+np4", tier="fused", **dims):
+    pipe = pl.build_pipeline(spec, use_kernels=tier, **{**DIMS, **dims})
     params = pipe.init_params(jax.random.key(0))
     return pipe, params, pipe.prepare(params)
 
@@ -104,16 +104,29 @@ def test_sat_aggregate_compiles(one_chip):
              jnp.zeros((R, k), bool), sharding=one_chip)
 
 
-def test_fused_step_compiles(one_chip):
-    pipe, params, aux = _student()
+#: the fused step's shapes: Wikipedia's, and GDELT's (paper Table II:
+#: 200-d static node features, no edge features, so the session's
+#: one-row edge placeholder; 16,682 actors, TGL arXiv:2203.14883)
+FUSED_SHAPES = {"wikipedia": {},
+                "gdelt": dict(n_nodes=16_682, n_edges=1, f_edge=0,
+                              f_feat=200)}
+
+
+@pytest.mark.parametrize("shape", sorted(FUSED_SHAPES))
+def test_fused_step_compiles(one_chip, shape):
+    pipe, params, aux = _student(**FUSED_SHAPES[shape])
+    cfg = pipe.cfg
     state = jax.eval_shape(lambda: pipe.resident(pipe.init_state()))
-    edge_tab = jax.eval_shape(ops.row_table,
-                              jax.ShapeDtypeStruct((E, 172), jnp.float32))
+    edge_tab = jax.eval_shape(ops.row_table, jax.ShapeDtypeStruct(
+        (cfg.n_edges, cfg.f_edge), jnp.float32))
+    node_tab = (jax.eval_shape(ops.row_table, jax.ShapeDtypeStruct(
+        (cfg.n_nodes, cfg.f_feat), jnp.float32)) if cfg.f_feat else None)
     zi = jnp.zeros((B,), jnp.int32)
     batch = (zi, zi, zi, jnp.zeros((B,), jnp.float32),
              jnp.zeros((B,), bool))
-    text = _compile(lambda st, b, ef: pipe.step(params, aux, st, b, ef),
-                    state, batch, edge_tab, sharding=one_chip)
+    text = _compile(lambda st, b, ef, nf: pipe.step(params, aux, st, b, ef,
+                                                    nf),
+                    state, batch, edge_tab, node_tab, sharding=one_chip)
     assert text.count("tpu_custom_call") >= 1
 
 
@@ -143,3 +156,82 @@ def test_mixed_cohort_round_compiles(one_chip):
     text = lowered.compile().as_text()
     # one Mosaic launch per fused lane, three per staged lane
     assert text.count("tpu_custom_call") >= 2 + 3
+
+
+def _entry_ops(text):
+    """``{instruction: (result type, opcode, op name)}`` of the compiled
+    program's entry computation: the operations a device trace shows as
+    ``XLA Ops`` events (the insides of fusions are not events of their
+    own)."""
+    import re
+    ops, inside = {}, False
+    for line in text.splitlines():
+        if line.startswith("ENTRY "):
+            inside = True
+            continue
+        if inside and line.startswith("}"):
+            break
+        m = inside and re.match(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if m:
+            label = re.search(r'metadata=\{op_name="([^"]*)"', line)
+            ops[m.group(1)] = (m.group(2), m.group(3),
+                               label.group(1) if label else "")
+    return ops
+
+
+def test_commit_ops_carry_the_lww_commit_scope(one_chip):
+    """The last-write-wins commits of a GDELT-shaped fused round (memory,
+    last update and mail flags; mail and its time stamp) compile to
+    entry-level operations whose op names carry ``lww_commit``: each
+    scatter into a table with the committer's scratch row (V + 1 rows),
+    the pad that appends that row and the slice that drops it. The copies
+    XLA inserts on its own (``copy``, ``copy-start``/``copy-done``) carry
+    no op name. The benchmark's ``round.lww_commit_ms`` sums the device
+    time of exactly the events labelled ``lww_commit``."""
+    import importlib.util
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "bench")
+    sys.path.insert(0, bench)
+    try:
+        import trace_reduce
+    finally:
+        sys.path.remove(bench)
+    n, v = 2, 2000
+    pipe, params, aux = _student(**{**FUSED_SHAPES["gdelt"], "n_nodes": v})
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((n,) + x.shape, x.dtype),
+        jax.eval_shape(lambda: pipe.resident(pipe.init_state())))
+    sb = (np.zeros((n, B), np.int32), np.zeros((n, B), np.int32),
+          np.zeros((n, B), np.int32), np.zeros((n, B), np.float32),
+          np.zeros((n, B), bool))
+    edge_tab = jax.ShapeDtypeStruct((1, 1, 128), jnp.float32)
+    node_tab = jax.ShapeDtypeStruct((v, 1, 256), jnp.float32)
+    rnd = pl.CoalescedRound([(pipe, aux, n)])
+    with ops.force_interpret(False):
+        lowered = rnd._fn.lower(*_shapes(((params,), (state,), sb, edge_tab,
+                                          node_tab), one_chip), (B,))
+    text = lowered.compile().as_text()
+    entry = _entry_ops(text)
+    lww = {k for k, (_t, _o, label) in entry.items() if "lww_commit" in label}
+    # every f32/bool table of V + 1 rows that an operation computes is the
+    # committer's (the fused datapath's int32 winner-row redirect is the
+    # one other such table); bitcasts and XLA's copies compute nothing
+    moved = ("bitcast", "copy", "copy-start", "copy-done")
+    scratch = {k for k, (t, op, _l) in entry.items() if op not in moved
+               and t.startswith((f"f32[{n},{v + 1}", f"pred[{n},{v + 1}"))}
+    assert scratch and scratch <= lww, sorted(scratch - lww)
+    assert not any(entry[k][2] for k, (_t, op, _l) in entry.items()
+                   if op.startswith("copy-")), "XLA's copies now carry a name"
+    kinds = [entry[k][2].rsplit("/", 1)[-1] for k in lww]
+    assert kinds.count("scatter") >= 6, kinds        # six committed tables
+    assert "concatenate" in kinds and "slice" in kinds, kinds
+    # the reader sums the labelled events, and only those
+    spec = importlib.util.spec_from_file_location(
+        "lww_reader", os.path.join(bench, "metrics", "round.lww_commit_ms.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    labels = trace_reduce.op_labels(text)
+    rec = {"window_rounds": 4, "trace": {"ops": {
+        (f"{k} {labels[k]}" if k in labels else k): 1e-3 for k in entry}}}
+    assert reader.read(rec) == pytest.approx(len(lww) * 1e-3 / 4 * 1e3)

@@ -22,10 +22,11 @@ silent — everything still computes the right numbers, just slower):
    launch exists to avoid: in ``stages.make_fused_step``'s ``datapath``
    and in ``kernels/ops.fused_step`` no ``jnp.concatenate``/``jnp.stack``
    (the kv concat) and no subscript gather of ``.memory`` / ``.mail`` /
-   ``edge_feats`` (the ``(B, k, Dkv)`` neighbor tensor — winner rows are
-   DMA'd inside the kernel, everything XLA-side is ids/timestamps
-   metadata); and ``kernels/fused_step.py`` itself must stay concat-free
-   (the kernel computes split matmuls).
+   ``edge_feats`` / ``node_feats`` (the ``(B, k, Dkv)`` neighbor tensor
+   and the tile's own feature rows — every row is DMA'd inside the
+   kernel, everything XLA-side is ids/timestamps metadata); and
+   ``kernels/fused_step.py`` itself must stay concat-free (the kernel
+   computes split matmuls).
 
 3. The round hot path must stay ASYNC: no unconditional
    ``block_until_ready`` (a device fence serializes the pipelined
@@ -88,7 +89,7 @@ STAGING = {"pad", "stack", "asarray", "concatenate"}
 #: jnp attributes that mean the fused datapath re-materializes (rule 2).
 FUSING = {"concatenate", "stack"}
 #: subscripted names/attributes that mean a neighbor-row gather is back.
-GATHERS = {"memory", "mail", "edge_feats"}
+GATHERS = {"memory", "mail", "edge_feats", "node_feats"}
 
 #: file -> ((scope, function, banned jnp attrs, ban gathers?), ...)
 #: ``scope`` is a class name, "*" for any nesting (module / closure), or
